@@ -191,7 +191,8 @@ def _export_obs(gw, result: dict, out_dir: str) -> dict:
           f"@ occupancy {prof['max_occupancy']} "
           f"({prof['dispatch_total']} total over {prof['steps']} steps)")
     for row in prof["phases"]:
-        drift = (f"{row['ratio']:.1f}x" if row["ratio"] is not None
+        # no drift column off a chip with a cost model (the CPU among them)
+        drift = (f"{row['ratio']:.1f}x" if row.get("ratio") is not None
                  else "-")
         print(f"  {row['phase']:<16} calls={row['calls']:<5} "
               f"sealed_B={row['sealed_bytes']:<9} "

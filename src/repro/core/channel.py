@@ -235,12 +235,15 @@ class SecureChannel:
         return sealed_lib.seal(x, self.jkey, self.fresh_nonce(span=TREE_LEAF_STRIDE),
                                spec)
 
-    def upload_tree(self, tree, spec: SealedSpec | None = None):
+    def upload_tree(self, tree, spec: SealedSpec | None = None, *,
+                    donate: bool = False):
+        """Seal a whole tree (Rule 1); ``donate`` consumes the plaintext
+        leaves (sealed.seal_tree)."""
         spec = spec or self.config.weights
         n_leaves = len(jax.tree_util.tree_leaves(tree))
         span = TREE_LEAF_STRIDE * (n_leaves + 1)
         return sealed_lib.seal_tree(tree, self.jkey, spec,
-                                    self.fresh_nonce(span=span))
+                                    self.fresh_nonce(span=span), donate=donate)
 
     def refresh_tree(self, sealed_tree, spec: SealedSpec | None = None):
         """Re-seal a tree under fresh nonce lanes (epoch bump).

@@ -209,39 +209,34 @@ def _row_index(shape) -> jax.Array:
     return idx
 
 
-def keystream_words_shaped(key: jax.Array, nonce, shape_rows: tuple, n_words: int):
-    """uint32 keystream of shape ``shape_rows + (n_words,)``.
+def _keystream_elems(tkey: jax.Array, rows: jax.Array, elems: jax.Array,
+                     dtype) -> jax.Array:
+    """Keystream for elements at (row, element index along the last axis).
 
-    One threefry call yields 2 words, so the block lattice is half the word
-    lattice; words are produced by interleaving (y0, y1).
+    Element e lives in word e // epw, which is half (word % 2) of block
+    word // 2.  Each element evaluates its own block, so the keystream is
+    one elementwise expression with no interleave or repeat between blocks
+    and elements: the compiler fuses it into the XOR that consumes it, and
+    never holds a keystream-sized temporary.
     """
-    tkey = derive_tensor_key(key, jnp.asarray(nonce, jnp.uint32))
-    n_blocks = (n_words + 1) // 2
-    bshape = tuple(shape_rows) + (n_blocks,)
-    row = _row_index(bshape)
-    block = jax.lax.broadcasted_iota(jnp.uint32, bshape, len(bshape) - 1)
-    y0, y1 = threefry2x32(tkey, row, block)
-    words = jnp.stack([y0, y1], axis=-1).reshape(*bshape[:-1], 2 * n_blocks)
-    return words[..., :n_words]
+    isz = jnp.dtype(dtype).itemsize
+    epw = 4 // isz
+    word = elems // np.uint32(epw) if epw > 1 else elems
+    y0, y1 = threefry2x32(tkey, rows, word >> np.uint32(1))
+    w = jnp.where((word & np.uint32(1)) == 0, y0, y1)
+    if epw == 1:
+        return w
+    shift = (elems % np.uint32(epw)) * np.uint32(8 * isz)
+    mask = np.uint32((1 << (8 * isz)) - 1)
+    return ((w >> shift) & mask).astype(_UINT_FOR_SIZE[isz])
 
 
 def keystream_like(key: jax.Array, nonce, shape, dtype) -> jax.Array:
     """Keystream with the tensor's own shape, as the matching unsigned dtype."""
     shape = tuple(shape) if len(shape) else (1,)
-    isz = jnp.dtype(dtype).itemsize
-    udt = _UINT_FOR_SIZE[isz]
-    last = shape[-1]
-    epw = 4 // isz
-    n_words = (last + epw - 1) // epw
-    words = keystream_words_shaped(key, nonce, shape[:-1], n_words)
-    if epw == 1:
-        return words[..., :last]
-    # expand each 32-bit word into epw sub-words along the last axis
-    rep = jnp.repeat(words, epw, axis=-1)[..., :last]
-    lane = jax.lax.broadcasted_iota(jnp.uint32, rep.shape, rep.ndim - 1) % np.uint32(epw)
-    bits = np.uint32(8 * isz)
-    sub = (rep >> (lane * bits)) & np.uint32((1 << (8 * isz)) - 1)
-    return sub.astype(udt)
+    tkey = derive_tensor_key(key, jnp.asarray(nonce, jnp.uint32))
+    elems = jax.lax.broadcasted_iota(jnp.uint32, shape, len(shape) - 1)
+    return _keystream_elems(tkey, _row_index(shape), elems, dtype)
 
 
 def keystream_for_rows(key: jax.Array, nonce, rows: jax.Array, last: int,
@@ -254,25 +249,11 @@ def keystream_for_rows(key: jax.Array, nonce, rows: jax.Array, last: int,
     without touching the rest — write cost proportional to bytes written,
     exactly the paper's §3.4 cost model.
     """
-    isz = jnp.dtype(dtype).itemsize
-    udt = _UINT_FOR_SIZE[isz]
-    epw = 4 // isz
-    n_words = (last + epw - 1) // epw
-    n_blocks = (n_words + 1) // 2
     tkey = derive_tensor_key(key, jnp.asarray(nonce, jnp.uint32))
-    bshape = rows.shape + (n_blocks,)
-    row_b = jnp.broadcast_to(rows[..., None].astype(jnp.uint32), bshape)
-    block = jax.lax.broadcasted_iota(jnp.uint32, bshape, len(bshape) - 1)
-    y0, y1 = threefry2x32(tkey, row_b, block)
-    words = jnp.stack([y0, y1], axis=-1).reshape(*bshape[:-1], 2 * n_blocks)
-    words = words[..., :n_words]
-    if epw == 1:
-        return words[..., :last]
-    rep = jnp.repeat(words, epw, axis=-1)[..., :last]
-    lane = jax.lax.broadcasted_iota(jnp.uint32, rep.shape, rep.ndim - 1) % np.uint32(epw)
-    bits = np.uint32(8 * isz)
-    sub = (rep >> (lane * bits)) & np.uint32((1 << (8 * isz)) - 1)
-    return sub.astype(udt)
+    shape = rows.shape + (last,)
+    row_b = jnp.broadcast_to(rows[..., None].astype(jnp.uint32), shape)
+    elems = jax.lax.broadcasted_iota(jnp.uint32, shape, len(shape) - 1)
+    return _keystream_elems(tkey, row_b, elems, dtype)
 
 
 def seal_bits_slice(x: jax.Array, key: jax.Array, nonce, rows: jax.Array):

@@ -49,11 +49,17 @@ def canon(x: jax.Array) -> jax.Array:
     return jnp.where(x == P31, jnp.uint32(0), x)
 
 
+def times2_16(x: jax.Array) -> jax.Array:
+    """(x * 2^16) mod p for any uint32 x (lazy result): with x folded below
+    2^31 + 1, x = xh*2^15 + xl  =>  x*2^16 = xh + xl*2^16 (mod p)."""
+    x = fold32(x)
+    return (x >> 15) + ((x & _MASK15) << 16)
+
+
 def mulmod(a: jax.Array, b: jax.Array) -> jax.Array:
     """(a * b) mod 2^31-1 for a, b < 2^31, via 16-bit limbs (lazy result).
 
-    a*b = a1*b1*2^32 + (a1*b0 + a0*b1)*2^16 + a0*b0, with 2^32 = 2 (mod p) and
-    x*2^16 folded via x = xh*2^15 + xl  =>  x*2^16 = xh + xl*2^16 (mod p).
+    a*b = a1*b1*2^32 + (a1*b0 + a0*b1)*2^16 + a0*b0, with 2^32 = 2 (mod p).
     """
     a = fold32(a)
     b = fold32(b)
@@ -64,10 +70,6 @@ def mulmod(a: jax.Array, b: jax.Array) -> jax.Array:
     hi = a1 * b1                       # < 2^32, exact in uint32? (2^16-1)^2 < 2^32: yes
     mid = fold32(a1 * b0) + fold32(a0 * b1)   # each < 2^31+1; sum < 2^32
     lo = a0 * b0                              # < 2^32, exact
-
-    def times2_16(x):  # (x * 2^16) mod p, x < 2^32
-        x = fold32(x)  # < 2^31 + 1
-        return (x >> 15) + ((x & _MASK15) << 16)
 
     hi_red = fold32(fold32(hi) * jnp.uint32(2))        # *2^32 == *2 mod p
     mid_red = times2_16(fold32(mid))
@@ -195,18 +197,18 @@ def tag_root(words: jax.Array, key: jax.Array, chunk_words: int,
 # of 128 so this holds for the default chunk sizes).
 # ---------------------------------------------------------------------------
 
-def _words_view(ct: jax.Array) -> jax.Array:
-    """View a shaped uintN ciphertext as uint32 words along the last axis."""
-    if ct.dtype == jnp.uint32:
-        return ct
-    per_word = 4 // jnp.dtype(ct.dtype).itemsize
-    last = ct.shape[-1]
-    pad = (-last) % per_word
-    if pad:
-        ct = jnp.concatenate(
-            [ct, jnp.zeros(ct.shape[:-1] + (pad,), ct.dtype)], axis=-1)
-    grouped = ct.reshape(*ct.shape[:-1], -1, per_word)
-    return jax.lax.bitcast_convert_type(grouped, jnp.uint32)
+def sum_mod(v: jax.Array, axis: int = -1) -> jax.Array:
+    """Sum residues mod p along ``axis`` (lazy result).
+
+    Two exact uint32 sums of 16-bit limbs (exact for up to 2^16 terms)
+    recombined mod p: the same residue as a tree of ``addmod``, as one
+    reduction the compiler fuses with its producer.
+    """
+    assert v.shape[axis] <= 1 << 16, v.shape
+    v = fold32(fold32(v))                                   # each <= p
+    lo = jnp.sum(v & _MASK16, axis=axis, dtype=jnp.uint32)
+    hi = jnp.sum(v >> 16, axis=axis, dtype=jnp.uint32)
+    return addmod(times2_16(hi), lo)
 
 
 def block_tags(ct: jax.Array, key: jax.Array, chunk_words: int,
@@ -216,9 +218,18 @@ def block_tags(ct: jax.Array, key: jax.Array, chunk_words: int,
     ct: uintN[..., last].  Returns uint32[..., n_chunks] canonical tags.
     Each tag authenticates one contiguous run of ``chunk_words`` 4-byte words
     (the paper's piece size s), keyed by position so chunks cannot be swapped.
+
+    Sub-word ciphertext is hashed element by element: word w_i packs its
+    elements little-endian, so (w_i + 1) * k_i = (e_0 + 1) * k_i
+    + sum_j e_j * (2^(bits*j) * k_i) mod p.  That is the same residue as
+    hashing the packed words, without ever packing them.
     """
-    w = _words_view(ct)
-    last_w = w.shape[-1]
+    isz = jnp.dtype(ct.dtype).itemsize
+    epw = 4 // isz                                  # elements per word
+    pad = (-ct.shape[-1]) % epw
+    if pad:
+        ct = jnp.pad(ct, [(0, 0)] * (ct.ndim - 1) + [(0, pad)])
+    last_w = ct.shape[-1] // epw
     # divisor-aligned chunking: pick the smallest chunk count >= words/s that
     # divides the word count exactly, so the reshape is layout-only and never
     # pads across shard boundaries (keeps tag computation shard-local).
@@ -226,21 +237,17 @@ def block_tags(ct: jax.Array, key: jax.Array, chunk_words: int,
     while last_w % n_chunks:
         n_chunks += 1
     chunk_words = last_w // n_chunks
-    w = w.reshape(*w.shape[:-1], n_chunks, chunk_words)
     keys = mac_keys(key, chunk_words, domain)                       # [cw]
-    wv = fold32(fold32(w) + jnp.uint32(1))
-    prod = mulmod(wv, keys)                                         # [..., nc, cw]
-    # O(log cw) tree reduction along the last axis
-    n = chunk_words
-    v = prod
-    while n > 1:
-        half = (n + 1) // 2
-        if half * 2 - n:
-            v = jnp.concatenate(
-                [v, jnp.zeros(v.shape[:-1] + (half * 2 - n,), jnp.uint32)], axis=-1)
-        v = addmod(v[..., 0::2], v[..., 1::2])
-        n = half
-    tag = v[..., 0]                                                 # [..., nc]
+    x = ct.reshape(*ct.shape[:-1], n_chunks, chunk_words * epw)
+    if epw == 1:
+        v = mulmod(fold32(fold32(x) + jnp.uint32(1)), keys)
+    else:
+        scale = np.array([(1 << (8 * isz * j)) % int(P31)
+                          for j in range(epw)], np.uint32)
+        kel = mulmod(keys[:, None], scale[None, :]).reshape(-1)    # [cw*epw]
+        one = (np.arange(chunk_words * epw) % epw == 0).astype(np.uint32)
+        v = mulmod(x.astype(jnp.uint32) + one, kel)
+    tag = sum_mod(v)                                                # [..., nc]
     # position mixing: global chunk index = row * n_chunks + chunk
     row = jnp.zeros(tag.shape, jnp.uint32)
     stride = 1

@@ -94,7 +94,10 @@ class AcceleratorModel:
         return self.step_cycles(w, prot) / self.step_cycles(w, Protection.NONE)
 
 
-# TPU v5e single-chip constants (used for roofline-style estimates)
+# TPU v5e single-chip constants (used for roofline-style estimates).
+# Peaks: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16 and
+# 819 GB/s HBM per chip.  The crypto rates are this repo's model, not
+# measurements.
 TPU_V5E = AcceleratorModel(
     name="tpu-v5e-sealed",
     flops_per_cycle=197e12 / 940e6,      # bf16 peak @ ~940 MHz
@@ -105,6 +108,11 @@ TPU_V5E = AcceleratorModel(
     mac_pipelined=True,
     chunk_bytes=2048,
 )
+
+
+# one row per chip, keyed by jax's ``device.device_kind``; a device with no
+# row (the CPU among them) is priced by no model, never as a chip it is not
+MODELS_BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E}
 
 
 def gemm_workload(name: str, m: int, n: int, k: int, dtype_bytes: int = 1,

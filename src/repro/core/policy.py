@@ -30,6 +30,13 @@ class Protection(enum.Enum):
 # staging buffer of the paper's security interface.
 DEFAULT_CHUNK_WORDS = 512
 
+# Weights are verified whole, on every launch that uses them, so a weight
+# piece may span a whole tensor row: 32768 words (128 KiB) covers every row
+# of the supported configs (granite-3-2b's widest is its 49155-wide
+# unembedding).  A piece that ends inside a row would cost the accelerator
+# a relayout of the whole tensor for every verification.
+WEIGHT_CHUNK_WORDS = 1 << 15
+
 
 @dataclasses.dataclass(frozen=True)
 class SealedSpec:
@@ -48,7 +55,7 @@ class SecurityConfig:
     leave the pod trust boundary, and checkpoints at rest.
     """
     enabled: bool = True
-    weights: SealedSpec = SealedSpec()
+    weights: SealedSpec = SealedSpec(chunk_words=WEIGHT_CHUNK_WORDS)
     grads: SealedSpec = SealedSpec()
     activations: SealedSpec = SealedSpec(protection=Protection.CTR)
     kv_cache: SealedSpec = SealedSpec()

@@ -203,11 +203,27 @@ def is_sealed(x) -> bool:
     return isinstance(x, SealedTensor)
 
 
-def seal_tree(tree, key: jax.Array, spec: SealedSpec, nonce_base: int = 0):
+_seal_leaf = {donate: jax.jit(seal, static_argnames=("spec",),
+                              donate_argnums=(0,) if donate else ())
+              for donate in (False, True)}
+
+
+def seal_tree(tree, key: jax.Array, spec: SealedSpec, nonce_base: int = 0,
+              *, donate: bool = False):
     """Seal every array leaf of a pytree, with distinct per-leaf nonces
-    spaced TREE_LEAF_STRIDE apart (the ResealCounter budget above)."""
+    spaced TREE_LEAF_STRIDE apart (the ResealCounter budget above).
+
+    Each leaf seals in one compiled program, so the keystream and MAC work
+    stream through the ciphertext instead of materializing leaf-sized
+    temporaries op by op.  ``donate`` hands each plaintext leaf to its
+    program, which writes the ciphertext into the leaf's buffer: the device
+    never holds both copies of the tree, and the caller's leaves are
+    deleted.
+    """
     leaves, treedef = jax.tree_util.tree_flatten(tree)
-    sealed = [seal(x, key, np.uint32(nonce_base + TREE_LEAF_STRIDE * i), spec)
+    seal_leaf = _seal_leaf[donate]
+    sealed = [seal_leaf(x, key, np.uint32(nonce_base + TREE_LEAF_STRIDE * i),
+                        spec)
               for i, x in enumerate(leaves)]
     return jax.tree_util.tree_unflatten(treedef, sealed)
 

@@ -43,6 +43,17 @@ def keystream_tile(k0, k1, rows, blocks):
     return jnp.stack([y0, y1], axis=-1).reshape(R, 2 * NB)
 
 
+def keystream_words(k0, k1, rows, words):
+    """rows/words: uint32 counter lattices of one shape -> keystream words.
+
+    Word w is half (w % 2) of block w // 2, evaluated lane by lane: the
+    same values as ``keystream_tile`` without its lane interleave, which a
+    TPU kernel cannot afford; each block is computed twice instead.
+    """
+    y0, y1 = threefry2x32(k0, k1, rows, words >> np.uint32(1))
+    return jnp.where((words & np.uint32(1)) == 0, y0, y1)
+
+
 # --- Mersenne-31 ops (see core.mac) ---------------------------------------
 
 P31 = np.uint32(0x7FFFFFFF)
@@ -58,6 +69,12 @@ def addmod(a, b):
     return fold32(fold32(fold32(a)) + fold32(fold32(b)))
 
 
+def times2_16(x):
+    """(x * 2^16) mod p for any uint32 x (lazy result)."""
+    x = fold32(x)
+    return (x >> np.uint32(15)) + ((x & _M15) << np.uint32(16))
+
+
 def mulmod(a, b):
     a = fold32(a)
     b = fold32(b)
@@ -66,8 +83,7 @@ def mulmod(a, b):
     hi = a1 * b1
     mid = fold32(a1 * b0) + fold32(a0 * b1)
     lo = a0 * b0
-    mid_f = fold32(mid)
-    mid_red = (mid_f >> np.uint32(15)) + ((mid_f & _M15) << np.uint32(16))
+    mid_red = times2_16(fold32(mid))
     hi_red = fold32(fold32(hi) * np.uint32(2))
     out = fold32(hi_red + mid_red)
     return fold32(out + fold32(lo))
@@ -76,3 +92,57 @@ def mulmod(a, b):
 def canon(x):
     x = fold32(fold32(x))
     return jnp.where(x == P31, jnp.uint32(0), x)
+
+
+# --- chunk tags (see core.mac.block_tags) ----------------------------------
+
+LANES = 128
+POS_MIX = np.uint32(0x9E3779B1)        # chunk position mixing
+
+
+def lane_sum_mod(v):
+    """[N, L] uint32 residues -> [N, 1] their sum mod p (lazy).
+
+    Two exact int32 sums of 16-bit limbs, recombined mod p: the residue of
+    a tree of ``addmod``, without lane slices narrower than a register."""
+    v = fold32(fold32(v))                               # each <= p
+    lo = jnp.sum((v & _M16).astype(jnp.int32), axis=1, keepdims=True)
+    hi = jnp.sum((v >> np.uint32(16)).astype(jnp.int32), axis=1,
+                 keepdims=True)
+    return addmod(times2_16(hi.astype(jnp.uint32)), lo.astype(jnp.uint32))
+
+
+def chunk_tag(words, keys, pos, k0):
+    """Multilinear tag of each row of ``words`` [N, cw] with ``keys`` [1, cw]
+    and position residues ``pos`` [N, 1]."""
+    v = mulmod(fold32(fold32(words) + np.uint32(1)), keys)
+    while v.shape[1] > LANES:                           # fold lane tiles
+        half = v.shape[1] // 2
+        v = addmod(v[:, :half], v[:, half:])
+    return canon(addmod(lane_sum_mod(v), mulmod(pos + np.uint32(1), k0)))
+
+
+# --- bf16 pairs in 32-bit words (element 2j in the low half) ---------------
+
+HI16 = np.uint32(0xFFFF0000)
+
+
+def words_of(x16):
+    """uint16[..., 2n] -> uint32[..., n]."""
+    return jax.lax.bitcast_convert_type(
+        x16.reshape(*x16.shape[:-1], x16.shape[-1] // 2, 2), jnp.uint32)
+
+
+def bf16_of_words(w):
+    """uint32[..., n] -> bf16[..., 2n], the inverse of ``words_of``."""
+    x16 = jax.lax.bitcast_convert_type(w, jnp.uint16)
+    return jax.lax.bitcast_convert_type(
+        x16.reshape(*w.shape[:-1], 2 * w.shape[-1]), jnp.bfloat16)
+
+
+def halves(w):
+    """Plaintext words -> (low-half bf16, high-half bf16), exactly, with no
+    lane interleave: each half is shifted into a float32's top bits."""
+    lo = jax.lax.bitcast_convert_type(w << np.uint32(16), jnp.float32)
+    hi = jax.lax.bitcast_convert_type(w & HI16, jnp.float32)
+    return lo.astype(jnp.bfloat16), hi.astype(jnp.bfloat16)

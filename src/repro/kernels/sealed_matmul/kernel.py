@@ -11,12 +11,18 @@ expression of the paper's "decrypt on demand at the SRAM boundary":
   * each fetched tile's chunk MAC (Mersenne-31 multilinear, chunk = one tile
     row-segment, i.e. the paper's piece size s = bk words) is recomputed and
     compared against the tag sidecar — "verify every fetched piece";
-  * the f32 accumulator lives in a VMEM scratch across the K grid dimension;
+  * the f32 accumulators live in VMEM scratch across the K grid dimension;
     mismatch counts accumulate into an i32 output (nonzero => poisoned launch).
+
+The kernel works on 32-bit words, two bf16 per word (low half = even
+column).  It never interleaves lanes: A's even and odd K columns multiply
+B's even and odd K rows (a sublane-strided load), and C's even and odd
+columns accumulate apart and are packed back into words on the way out.
 
 Chunk/tag layout: tags_a uint32[M, K/bk] (chunk c of row r covers A words
 [r, c*bk/2 : (c+1)*bk/2]), tags_b uint32[K, N/bn] likewise.  Tag position
-mixing matches core.mac.block_tags with n_chunks = K/bk (resp. N/bn).
+mixing matches core.mac.block_tags with n_chunks = K/bk (resp. N/bn).  A
+grid step reads whole tag rows and picks its chunk's lane by mask.
 """
 from __future__ import annotations
 
@@ -29,88 +35,77 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import common
+from ..common import halves
 
 BM, BK, BN = 256, 256, 256
 
 
-def _unseal_tile_bf16(ct16, k0, k1, row0, word0):
-    """ct16: uint16[R, C] tile (C even). Counters: rows row0+i, words word0+j.
-
-    Returns bf16[R, C].  Word lattice: element (r, c) lives in 32-bit word
-    (word0*? ...) — here `word0` is the word offset of the tile's first
-    column: word(c) = word0 + c // 2; block(c) = word(c) // 2.
-    """
-    R, C = ct16.shape
-    nb = C // 4 if C % 4 == 0 else (C // 2 + 1) // 2
-    # generate the covering 32-bit blocks: columns c in [0, C) map to words
-    # w = word0 + c//2, blocks b = w//2.  Tiles are aligned (word0 % 2 == 0).
-    nwords = C // 2
-    nblocks = nwords // 2
-    rows = row0 + jax.lax.broadcasted_iota(jnp.uint32, (R, nblocks), 0)
-    blocks = (word0 // jnp.uint32(2)
-              + jax.lax.broadcasted_iota(jnp.uint32, (R, nblocks), 1))
-    ks32 = common.keystream_tile(k0, k1, rows, blocks)      # [R, nwords]
-    ct32 = jax.lax.bitcast_convert_type(
-        ct16.reshape(R, nwords, 2), jnp.uint32)             # [R, nwords]
-    pt32 = ct32 ^ ks32
-    pt16 = jax.lax.bitcast_convert_type(pt32, jnp.uint16)   # [R, nwords, 2]
-    return jax.lax.bitcast_convert_type(pt16, jnp.bfloat16).reshape(R, C)
+def _bits(x):
+    """bf16-valued f32 -> its 16 bits in the high half of a word."""
+    return jax.lax.bitcast_convert_type(
+        x.astype(jnp.bfloat16).astype(jnp.float32), jnp.uint32) & common.HI16
 
 
-def _tile_tags(ct16, keys, row0, chunk_idx, n_chunks_total):
-    """Recompute the chunk tag of a fetched tile (chunk = tile row-segment)."""
-    R, C = ct16.shape
-    nwords = C // 2
-    w = jax.lax.bitcast_convert_type(ct16.reshape(R, nwords, 2), jnp.uint32)
-    wv = common.fold32(common.fold32(w) + jnp.uint32(1))
-    v = common.mulmod(wv, keys)                             # [R, nwords]
-    n = nwords
-    while n > 1:
-        half = n // 2
-        v = common.addmod(v[:, :half], v[:, half:n])
-        n = half
-    tag = v[:, 0]
-    rows = row0 + jax.lax.broadcasted_iota(jnp.uint32, (R, 1), 0)[:, 0]
-    pos = common.canon((rows * jnp.uint32(n_chunks_total) + chunk_idx)
-                       * jnp.uint32(0x9E3779B1))
-    return common.canon(common.addmod(tag, common.mulmod(pos + jnp.uint32(1),
-                                                         keys[0, 0])))
+def _unseal(ct, k0, k1, rows, word0):
+    words = word0 + jax.lax.broadcasted_iota(jnp.uint32, ct.shape, 1)
+    return ct ^ common.keystream_words(k0, k1, rows, words)
 
 
-def _sealed_matmul_kernel(keya_ref, keyb_ref, mkeys_ref, a_ref, b_ref,
-                          tag_a_ref, tag_b_ref, o_ref, bad_ref, acc_ref, *,
+def _sealed_matmul_kernel(keys_ref, mkeys_ref, a_ref, b_ref, tag_a_ref,
+                          tag_b_ref, o_ref, bad_ref, acc_e_ref, acc_o_ref, *,
                           bm, bk, bn, nk, n_chunks_a, n_chunks_b, verify):
     i = pl.program_id(0)
     j = pl.program_id(1)
     k = pl.program_id(2)
-
-    a = _unseal_tile_bf16(a_ref[...], keya_ref[0, 0], keya_ref[0, 1],
-                          jnp.uint32(i * bm), jnp.uint32(k * (bk // 2)))
-    b = _unseal_tile_bf16(b_ref[...], keyb_ref[0, 0], keyb_ref[0, 1],
-                          jnp.uint32(k * bk), jnp.uint32(j * (bn // 2)))
+    ka0, ka1, kb0, kb1, m0 = (keys_ref[n] for n in range(5))
 
     @pl.when(k == 0)
     def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        acc_e_ref[...] = jnp.zeros_like(acc_e_ref)
+        acc_o_ref[...] = jnp.zeros_like(acc_o_ref)
         bad_ref[...] = jnp.zeros_like(bad_ref)
 
-    acc_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    row_a = ((i * bm).astype(jnp.uint32)
+             + jax.lax.broadcasted_iota(jnp.uint32, (bm, 1), 0))
+    a_lo, a_hi = halves(_unseal(a_ref[...], ka0, ka1, row_a,
+                                (k * (bk // 2)).astype(jnp.uint32)))
+    half_k = jax.lax.broadcasted_iota(jnp.uint32, (bk // 2, 1), 0)
+    word_b = (j * (bn // 2)).astype(jnp.uint32)
+    row_b = (k * bk).astype(jnp.uint32) + 2 * half_k
+    be_lo, be_hi = halves(_unseal(b_ref[pl.ds(0, bk // 2, stride=2), :],
+                                  kb0, kb1, row_b, word_b))
+    bo_lo, bo_hi = halves(_unseal(b_ref[pl.ds(1, bk // 2, stride=2), :],
+                                  kb0, kb1, row_b + np.uint32(1), word_b))
+
+    def dot(x, y):
+        return jax.lax.dot_general(x, y, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    acc_e_ref[...] += dot(a_lo, be_lo) + dot(a_hi, bo_lo)
+    acc_o_ref[...] += dot(a_lo, be_hi) + dot(a_hi, bo_hi)
 
     if verify:
         mk = mkeys_ref[...]                                  # [1, bk//2]
-        ta = _tile_tags(a_ref[...], mk, jnp.uint32(i * bm), jnp.uint32(k),
-                        n_chunks_a)
-        tb = _tile_tags(b_ref[...], mk, jnp.uint32(k * bk), jnp.uint32(j),
-                        n_chunks_b)
-        bad = (jnp.sum((ta != tag_a_ref[:, 0]).astype(jnp.int32))
-               + jnp.sum((tb != tag_b_ref[:, 0]).astype(jnp.int32)))
-        bad_ref[0, 0] += bad
+
+        def mismatches(ct, tags, rows, chunk, n_chunks):
+            pos = common.canon((rows * np.uint32(n_chunks)
+                                + chunk.astype(jnp.uint32)) * common.POS_MIX)
+            want = common.chunk_tag(ct, mk, pos, m0)         # [R, 1]
+            lane = jax.lax.broadcasted_iota(jnp.int32, tags.shape, 1)
+            return jnp.sum(((lane == chunk) & (tags != want)).astype(jnp.int32),
+                           keepdims=True)
+
+        row_bt = ((k * bk).astype(jnp.uint32)
+                  + jax.lax.broadcasted_iota(jnp.uint32, (bk, 1), 0))
+        bad = (mismatches(a_ref[...], tag_a_ref[...], row_a, k, n_chunks_a)
+               + mismatches(b_ref[...], tag_b_ref[...], row_bt, j,
+                            n_chunks_b))
+        bad_ref[...] += jnp.broadcast_to(bad, bad_ref.shape)
 
     @pl.when(k == nk - 1)
     def _store():
-        o_ref[...] = acc_ref[...].astype(jnp.bfloat16)
+        o_ref[...] = ((_bits(acc_e_ref[...]) >> np.uint32(16))
+                      | _bits(acc_o_ref[...]))
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "bn", "verify",
@@ -129,30 +124,31 @@ def sealed_matmul(a_ct: jax.Array, b_ct: jax.Array, tags_a: jax.Array,
     K2, N = b_ct.shape
     assert K == K2 and M % bm == 0 and K % bk == 0 and N % bn == 0
     nk = K // bk
-    grid = (M // bm, N // bn, nk)
-    out_shape = (jax.ShapeDtypeStruct((M, N), jnp.bfloat16),
-                 jax.ShapeDtypeStruct((M // bm, N // bn), jnp.int32))
+    gm, gn = M // bm, N // bn
     kern = functools.partial(
         _sealed_matmul_kernel, bm=bm, bk=bk, bn=bn, nk=nk,
         n_chunks_a=K // bk, n_chunks_b=N // bn, verify=verify)
-    return pl.pallas_call(
+    keys = jnp.concatenate([key_a, key_b, mac_keys_arr[:1]]).astype(jnp.uint32)
+    c_words, bad = pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(gm, gn, nk),
         in_specs=[
-            pl.BlockSpec((1, 2), lambda i, j, k: (0, 0)),
-            pl.BlockSpec((1, 2), lambda i, j, k: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # key_a, key_b, mac key 0
             pl.BlockSpec((1, bk // 2), lambda i, j, k: (0, 0)),
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bm, 1), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, 1), lambda i, j, k: (k, j)),
+            pl.BlockSpec((bm, bk // 2), lambda i, j, k: (i, k)),
+            pl.BlockSpec((bk, bn // 2), lambda i, j, k: (k, j)),
+            pl.BlockSpec((bm, K // bk), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((bk, N // bn), lambda i, j, k: (k, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (i, j)),
+            pl.BlockSpec((bm, bn // 2), lambda i, j, k: (i, j)),
+            pl.BlockSpec((8, 128), lambda i, j, k: (i, j)),
         ],
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        out_shape=(jax.ShapeDtypeStruct((M, N // 2), jnp.uint32),
+                   jax.ShapeDtypeStruct((gm * 8, gn * 128), jnp.int32)),
+        scratch_shapes=[pltpu.VMEM((bm, bn // 2), jnp.float32),
+                        pltpu.VMEM((bm, bn // 2), jnp.float32)],
         interpret=interpret,
-    )(key_a.reshape(1, 2), key_b.reshape(1, 2),
-      mac_keys_arr.reshape(1, -1), a_ct, b_ct, tags_a, tags_b)
+    )(keys, mac_keys_arr.reshape(1, -1).astype(jnp.uint32),
+      common.words_of(a_ct), common.words_of(b_ct), tags_a, tags_b)
+    return common.bf16_of_words(c_words), bad[::8, ::128]

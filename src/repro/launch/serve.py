@@ -17,6 +17,8 @@ the streaming Monitor's thresholds, e.g. ``--slo ttft_p95_ms=250``.
 from __future__ import annotations
 
 import argparse
+import os
+import pathlib
 import sys
 import time
 
@@ -28,6 +30,18 @@ from ..core.channel import SecureChannel
 from ..models import registry
 from ..obs import MonitorConfig, parse_slo_overrides, render_gateway
 from ..serve import SecureGateway, ServeEngine
+
+REPO = pathlib.Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> None:
+    """Keep compiled programs across runs in ``<repo>/.jax_cache``, unless
+    JAX_COMPILATION_CACHE_DIR names a directory (jax reads it itself).  A
+    fixed path: the cache directory is part of what a cached entry matches.
+    Entry points call this; importing the library never does."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(REPO / ".jax_cache"))
 
 
 def _run_gateway(cfg, params, args) -> None:
@@ -41,7 +55,7 @@ def _run_gateway(cfg, params, args) -> None:
                        open_pages=not args.whole_page_reseal,
                        prefill_chunk=args.prefill_chunk,
                        trace=bool(args.trace),
-                       monitor_config=mon_cfg)
+                       monitor_config=mon_cfg, donate_params=True)
     rng = np.random.RandomState(0)
     rids = []
     for i in range(args.requests):
@@ -104,7 +118,7 @@ def _run_fixed(cfg, params, args) -> None:
     channel = (SecureChannel.establish() if args.security == "trusted"
                else SecureChannel.insecure())
     if args.security == "trusted":
-        params = channel.upload_tree(params)
+        params = channel.upload_tree(params, donate=True)
     max_len = args.max_prompt + args.max_new + 4
     engine = ServeEngine(cfg=cfg, params=params, channel=channel,
                          max_len=max_len)
@@ -168,9 +182,9 @@ def main() -> None:
     ap.add_argument("--full", dest="smoke", action="store_false")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = configs.get_config(args.arch, smoke=args.smoke)
-    model = registry.get_model(cfg)
-    params = model.init(jax.random.PRNGKey(0), cfg)
+    params = registry.init_params(jax.random.PRNGKey(0), cfg)
     if args.engine == "gateway" and cfg.family == "dense":
         _run_gateway(cfg, params, args)
     else:

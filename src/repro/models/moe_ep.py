@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
-
 
 def moe_ffn_ep(p, cfg, x, mesh, data_axis="data", model_axis="model"):
     """Drop-in EP replacement for moe.moe_ffn. x: [B, S, D] -> [B, S, D].
@@ -74,7 +72,7 @@ def moe_ffn_ep(p, cfg, x, mesh, data_axis="data", model_axis="model"):
         return jax.lax.psum(y_partial, model_axis)             # TP-style combine
 
     xt = x.reshape(T, D)
-    out = compat.shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(data_axis), P(), P(model_axis), P(model_axis),
                   P(model_axis)),

@@ -10,7 +10,10 @@ plus cache/state constructors, unified here as ``make_decode_state``.
 """
 from __future__ import annotations
 
+import functools
 import types
+
+import jax
 
 from . import encdec, moe, rwkv, ssm, transformer
 
@@ -26,6 +29,14 @@ _FAMILY = {
 
 def get_model(cfg) -> types.ModuleType:
     return _FAMILY[cfg.family]
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def init_params(key, cfg):
+    """``get_model(cfg).init`` as one compiled program.  The device then
+    holds the weights and nothing beside them; run op by op, the init also
+    holds each leaf's float32 draw and its intermediates."""
+    return get_model(cfg).init(key, cfg)
 
 
 def make_decode_state(cfg, batch: int, max_len: int, src_len: int = 0,

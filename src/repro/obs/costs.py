@@ -172,25 +172,27 @@ class CostLedger:
         term (CTR throughput + pipeline fill + MAC chunk tags) prices the
         bytes each phase charged, converted to us at ``clock_hz``.  Rows
         with no bytes (host-copy phases, umbrella spans) predict 0 and
-        report ratio None.
+        report ratio None.  With ``model`` None the rows carry the
+        measured and counted columns only.
         """
         out = []
         order = {p: i for i, p in enumerate(PHASES)}
         totals = self.phase_totals()
         for phase in sorted(totals, key=lambda p: order.get(p, len(order))):
             cols = totals[phase]
-            cycles = model.crypto_cycles(cols["sealed_bytes"])
-            predicted_us = 1e6 * cycles / clock_hz
-            ratio = (cols["wall_us"] / predicted_us if predicted_us > 0
-                     else None)
-            out.append({"phase": phase, "calls": cols["calls"],
-                        "dispatches": cols["dispatches"],
-                        "sealed_bytes": cols["sealed_bytes"],
-                        "cipher_blocks": cols["cipher_blocks"],
-                        "mac_ops": cols["mac_ops"],
-                        "wall_us": cols["wall_us"],
-                        "predicted_us": predicted_us,
-                        "ratio": ratio})
+            row = {"phase": phase, "calls": cols["calls"],
+                   "dispatches": cols["dispatches"],
+                   "sealed_bytes": cols["sealed_bytes"],
+                   "cipher_blocks": cols["cipher_blocks"],
+                   "mac_ops": cols["mac_ops"],
+                   "wall_us": cols["wall_us"]}
+            if model is not None:
+                cycles = model.crypto_cycles(cols["sealed_bytes"])
+                predicted_us = 1e6 * cycles / clock_hz
+                row["predicted_us"] = predicted_us
+                row["ratio"] = (cols["wall_us"] / predicted_us
+                                if predicted_us > 0 else None)
+            out.append(row)
         return out
 
     def reset_window(self) -> None:

@@ -190,17 +190,20 @@ class Profiler:
     def report(self, model=None, clock_hz: float = 940e6) -> dict:
         """The BENCH_profile.json document (benchmarks/serve_gateway.py).
 
-        ``model`` defaults to core.overhead.TPU_V5E for the predicted-vs-
-        measured drift table; the deterministic columns (dispatches_per_
-        step, per-phase sealed_bytes / cipher_blocks / mac_ops / calls)
-        are what tools/bench_diff.py gates on.
+        ``model`` defaults to the cost model of the device jax runs on
+        (core.overhead.MODELS_BY_DEVICE_KIND); a device with no model, the
+        CPU among them, gets no predicted-vs-measured drift columns.  The
+        deterministic columns (dispatches_per_step, per-phase sealed_bytes
+        / cipher_blocks / mac_ops / calls) are what tools/bench_diff.py
+        gates on.
         """
         if model is None:
-            from ..core.overhead import TPU_V5E
-            model = TPU_V5E
+            import jax
+            from ..core.overhead import MODELS_BY_DEVICE_KIND
+            model = MODELS_BY_DEVICE_KIND.get(jax.devices()[0].device_kind)
         return {
             "benchmark": "profile",
-            "model": getattr(model, "name", str(model)),
+            "model": None if model is None else model.name,
             "steps": self.steps,
             "max_occupancy": self.max_occupancy,
             "dispatch_total": self._dispatch_total,

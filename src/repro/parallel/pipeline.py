@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 from ..core import cipher
 from ..models import layers as L
 from ..models import transformer as TF
@@ -148,7 +147,7 @@ def make_pipelined_loss(cfg, mesh, n_stages: int, n_micro: int,
         # all stages must return the same value: sum over the stage axis
         return jax.lax.psum(loss_acc, axis) / M
 
-    staged = compat.shard_map(
+    staged = jax.shard_map(
         staged_loss, mesh=mesh,
         in_specs=(_param_specs_staged(), P()),
         out_specs=P(), axis_names={axis}, check_vma=False)
@@ -170,7 +169,7 @@ def make_pipelined_loss(cfg, mesh, n_stages: int, n_micro: int,
                  for k, v in g.items()}
             return l, g
         specs = _param_specs_staged()
-        return compat.shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=(specs, P()),
             out_specs=(P(), specs), check_vma=False
         )(params_staged, batch)

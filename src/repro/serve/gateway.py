@@ -56,7 +56,8 @@ class SecureGateway:
                  store: SealedStore | None = None, open_pages: bool = True,
                  prefill_chunk: int = 0, trace: bool = False,
                  monitor: bool = True,
-                 monitor_config: MonitorConfig | None = None):
+                 monitor_config: MonitorConfig | None = None,
+                 donate_params: bool = False):
         """open_pages: slice-seal the tail page of each sequence (per-token
         seal cost O(bytes written), paper §3.4) instead of re-sealing the
         whole page every decode step.  False keeps the legacy whole-page
@@ -74,7 +75,11 @@ class SecureGateway:
         every step and let it drive scheduler actions (tamper-storm
         quarantine, occupancy spill, nonce-lane refresh) over its action
         bus.  monitor_config tunes the thresholds (obs/rules.py); latency
-        SLO bounds default off, security/headroom rules default on."""
+        SLO bounds default off, security/headroom rules default on.
+
+        donate_params: a trusted gateway seals ``params`` into the buffers
+        of its plaintext leaves, which are deleted, so the device holds the
+        weights once; for a caller that does not reuse ``params``."""
         self.cfg = cfg
         sec = (SecurityConfig() if security == "trusted"
                else SecurityConfig.off())
@@ -97,7 +102,8 @@ class SecureGateway:
         self.profiler = Profiler(registry=self.registry, tracer=self.tracer,
                                  chunk_words=chunk_words)
         sealed = sec.enabled
-        params_dev = provider.upload_tree(params) if sealed else params
+        params_dev = (provider.upload_tree(params, donate=donate_params)
+                      if sealed else params)
         self.pool = PagedKVPool(
             n_pages=n_pages, page_size=page_size, n_layers=cfg.n_layers,
             n_kv_heads=cfg.n_kv_heads, hd=cfg.hd, dtype=cfg.act_dtype,

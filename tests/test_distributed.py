@@ -24,14 +24,13 @@ def test_sealed_crosspod_allreduce_matches_plain():
     import jax, jax.numpy as jnp, numpy as np
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from repro import compat
     from repro.parallel import collectives
     from repro.launch.mesh import make_smoke_mesh
     mesh = make_smoke_mesh(8, pods=2)
     key = jnp.array([5, 9], jnp.uint32)
     x = jax.random.normal(jax.random.PRNGKey(0), (16, 32), jnp.float32)
     for quant, tol in ((False, 1e-6), (True, 0.02)):
-        f = jax.jit(compat.shard_map(
+        f = jax.jit(jax.shard_map(
             lambda xl: collectives.sealed_allreduce_pod(
                 xl, key, jnp.uint32(7), 2, mean=True, quantize=quant),
             mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),

@@ -12,6 +12,7 @@ ENV = dict(os.environ,
 def test_ep_dispatch_matches_global_loss_and_grads():
     code = """
     import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import AxisType
     from repro import configs
     from repro.models import registry
     from repro.parallel import sharding as shd
@@ -25,7 +26,8 @@ def test_ep_dispatch_matches_global_loss_and_grads():
     params = m.init(jax.random.PRNGKey(0), cfg)
     tok = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg.vocab)
     batch = {"tokens": tok, "labels": tok}
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     with shd.use(shd.make_ctx(mesh)):
         l0, g0 = jax.jit(jax.value_and_grad(
             lambda p: m.loss(p, cfg, batch)))(params)

@@ -12,6 +12,7 @@ ENV = dict(os.environ,
 def test_sealed_pipeline_matches_reference():
     code = """
     import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import AxisType
     from repro.models.config import ModelConfig
     from repro.models import registry
     from repro.parallel.pipeline import make_pipelined_loss, \\
@@ -32,7 +33,8 @@ def test_sealed_pipeline_matches_reference():
         m.loss(params, cfg, {"tokens": tok[i], "labels": tok[i]})
         for i in range(M)]))
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     staged = stack_params_by_stage(params, 2)
     key = jnp.array([5, 6], jnp.uint32)
     for seal in (None, key):

@@ -29,6 +29,7 @@ import numpy as np
 import pytest
 
 from repro import configs
+from repro.core.overhead import TPU_V5E
 from repro.models import registry
 from repro.obs import MonitorConfig, PHASES
 from repro.serve import SecureGateway
@@ -167,7 +168,7 @@ def test_ledger_exact_under_prefix_cow(setup, gw):
 def test_profile_report_document_and_drift_table(setup, gw):
     """profile_report() = the BENCH_profile.json document: dispatch
     accounting + per-phase drift rows priced by core/overhead.py."""
-    rep = gw.profile_report()
+    rep = gw.profile_report(model=TPU_V5E)
     assert rep["benchmark"] == "profile"
     assert rep["model"] == "tpu-v5e-sealed"
     assert rep["steps"] == gw.profiler.steps > 0
@@ -184,6 +185,19 @@ def test_profile_report_document_and_drift_table(setup, gw):
     # 8 bytes per keystream block, k+v lanes: blocks = ceil(bytes / 8)
     assert dec["cipher_blocks"] == -(-dec["sealed_bytes"] // 8)
     assert json.dumps(rep)                 # serializable as-is
+
+
+def test_profile_report_prices_only_a_known_chip(setup, gw):
+    """With no model given, the report prices the run with the cost model
+    of the device it ran on; the CPU has none, so the rows carry only the
+    measured and counted columns and no drift."""
+    rep = gw.profile_report()
+    assert jax.devices()[0].platform == "cpu"
+    assert rep["model"] is None
+    assert rep["phases"]
+    for row in rep["phases"]:
+        assert "predicted_us" not in row and "ratio" not in row
+        assert row["wall_us"] > 0 or row["calls"] == 0
 
 
 def _run_bench_diff(tmp_path, baseline: dict, current: dict):
@@ -226,7 +240,7 @@ def test_bench_diff_gates_dispatches_and_phase_costs(setup, gw, tmp_path):
     noisy = json.loads(json.dumps(rep))
     for row in noisy["phases"]:
         row["wall_us"] *= 40.0
-        if row["ratio"]:
+        if row.get("ratio"):
             row["ratio"] *= 40.0
     assert _run_bench_diff(tmp_path, rep, noisy).returncode == 0
 

@@ -26,6 +26,27 @@ def test_seal_unseal_tree_and_tamper(key):
     assert not bool(ok2)
 
 
+def test_seal_tree_donating_consumes_plaintext_and_matches(key):
+    spec = SealedSpec(chunk_words=128)
+
+    def make():
+        return {"w": jnp.arange(16 * 128, dtype=jnp.float32)
+                .reshape(16, 128).astype(jnp.bfloat16),
+                "b": jnp.full((128,), 3.0, jnp.float32)}
+
+    kept = sealed.seal_tree(make(), key, spec, nonce_base=7)
+    params = make()
+    donated = sealed.seal_tree(params, key, spec, nonce_base=7, donate=True)
+    assert all(x.is_deleted() for x in jax.tree_util.tree_leaves(params))
+    for a, b in zip(jax.tree_util.tree_leaves(kept),
+                    jax.tree_util.tree_leaves(donated)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    out, ok = sealed.unseal_tree(donated, key)
+    assert bool(ok)
+    np.testing.assert_array_equal(np.asarray(out["w"], np.float32),
+                                  np.asarray(make()["w"], np.float32))
+
+
 def test_replay_detected_via_nonce_binding(key):
     spec = SealedSpec(chunk_words=64)
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 256), jnp.float32)
