@@ -125,6 +125,11 @@ class Histogram(_Metric):
         return len(self._values)
 
     @property
+    def values(self) -> list[float]:
+        """A copy of this window's observations."""
+        return list(self._values)
+
+    @property
     def sum(self) -> float:
         return float(sum(self._values))
 

@@ -16,7 +16,13 @@ Design constraints:
 
   * cheap when on — an event is one dict append, timestamps come from
     ``time.monotonic`` once per call, nothing is serialized until export;
-  * free when off — ``Tracer(enabled=False)`` short-circuits every emit;
+  * free when off — ``Tracer(enabled=False)`` short-circuits every emit
+    into the buffer;
+  * on the profiler's clock — every ``span`` also enters a
+    ``jax.profiler.TraceAnnotation`` of the same name and args, enabled or
+    not, so a ``jax.profiler`` trace holds the program's host phases beside
+    the device's operations (with no profiler session running, an
+    annotation costs about a microsecond and records nothing);
   * two export formats — newline-delimited JSON (one event per line, the
     streaming/greppable form) and the Chrome JSON object
     ``{"traceEvents": [...]}`` that opens directly in Perfetto
@@ -31,6 +37,8 @@ from __future__ import annotations
 import json
 import time
 from contextlib import contextmanager
+
+import jax
 
 # virtual-thread layout (see module docstring)
 TID_ENGINE = 0
@@ -139,16 +147,21 @@ class Tracer:
     @contextmanager
     def span(self, name: str, *, cat: str = "serve", tid: int = TID_ENGINE,
              args: dict | None = None):
-        """Context-managed complete span around a code block."""
-        if not self.enabled:
-            yield
-            return
-        t0 = self.now_us()
-        try:
-            yield
-        finally:
-            self.complete(name, t0, self.now_us(), cat=cat, tid=tid,
-                          args=args)
+        """Context-managed complete span around a code block.
+
+        Always a ``jax.profiler.TraceAnnotation`` (args become its stats:
+        keep values scalar, with no ``,`` or ``#``); a buffered span too
+        when the tracer is enabled."""
+        with jax.profiler.TraceAnnotation(name, **(args or {})):
+            if not self.enabled:
+                yield
+                return
+            t0 = self.now_us()
+            try:
+                yield
+            finally:
+                self.complete(name, t0, self.now_us(), cat=cat, tid=tid,
+                              args=args)
 
     # -- export ----------------------------------------------------------
     def _metadata_events(self) -> list[dict]:

@@ -187,6 +187,10 @@ class PagedEngine:
         Per-page verification routes by trusted-side page state: CLOSED
         pages check the whole-page chunk tags, OPEN pages check the
         accumulated per-slot slice tags for the written prefix (< fill).
+
+        Named scopes: ``kv_gather`` (the page-table gathers, the relayout
+        and the length mask) and ``kv_unseal`` (keystream, MAC verify and
+        the per-sequence verdict).
         """
         cfg = self.cfg
         (k_ct, v_ct, k_tags, v_tags, k_stags, v_stags, nonces, keys,
@@ -195,56 +199,64 @@ class PagedEngine:
         ps = self.pool.page_size
         T = P * ps
         Lc, K, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
-        flat_pt = page_tables.reshape(-1)
-        kp_ct = k_ct[flat_pt]
-        vp_ct = v_ct[flat_pt]
-        if self.pool.sealed:
-            kpl, vpl, ok_page = jax.vmap(
-                lambda kc, vc, kt, vt, kw, nn: kv_pager.unseal_page(
-                    kc, vc, kt, vt, kw, nn, cfg.act_dtype,
-                    self.pool.chunk_words)
-            )(kp_ct, vp_ct, k_tags[flat_pt], v_tags[flat_pt],
-              keys[flat_pt], nonces[flat_pt])
-        else:
-            kpl = jax.lax.bitcast_convert_type(kp_ct, cfg.act_dtype)
-            vpl = jax.lax.bitcast_convert_type(vp_ct, cfg.act_dtype)
-            ok_page = jnp.ones((B * P,), bool)
-        ok_page = ok_page.reshape(B, P)
-        if self.pool.sealed and self.open_pages:
-            # by construction only each lane's TAIL page can be OPEN (full
-            # pages close, later pages are empty), so the slice-tag path
-            # runs on one page per lane, not B*P: its verdict overrides the
-            # whole-page check exactly where the trusted state says OPEN
-            tail_idx = jnp.clip(seq_lens // ps, 0, P - 1)         # [B]
-            tail_pp = jnp.take_along_axis(page_tables, tail_idx[:, None],
-                                          axis=1)[:, 0]
-            ok_open = jax.vmap(
-                lambda pp: kv_pager.verify_open_page(
-                    k_ct[pp], v_ct[pp], k_stags[pp], v_stags[pp],
-                    keys[pp], nonces[pp], fill[pp],
-                    self.pool.chunk_words)
-            )(tail_pp)
-            ok_closed_tail = jnp.take_along_axis(ok_page, tail_idx[:, None],
-                                                 axis=1)[:, 0]
-            ok_tail = jnp.where(open_flags[tail_pp], ok_open,
-                                ok_closed_tail)
-            ok_page = ok_page.at[jnp.arange(B), tail_idx].set(ok_tail)
-        # only pages holding valid positions count toward a slot's verdict,
-        # and idle lanes (scratch-page walks over garbage) never fail
-        page_used = (jnp.arange(P)[None, :] * ps) < seq_lens[:, None]
-        ok_seq = (jnp.all(ok_page | ~page_used, axis=1) & okp) | ~active
+        with jax.named_scope("kv_gather"):
+            flat_pt = page_tables.reshape(-1)
+            kp_ct = k_ct[flat_pt]
+            vp_ct = v_ct[flat_pt]
+            if self.pool.sealed:
+                page_meta = (k_tags[flat_pt], v_tags[flat_pt],
+                             keys[flat_pt], nonces[flat_pt])
+        with jax.named_scope("kv_unseal"):
+            if self.pool.sealed:
+                kpl, vpl, ok_page = jax.vmap(
+                    lambda kc, vc, kt, vt, kw, nn: kv_pager.unseal_page(
+                        kc, vc, kt, vt, kw, nn, cfg.act_dtype,
+                        self.pool.chunk_words)
+                )(kp_ct, vp_ct, *page_meta)
+            else:
+                kpl = jax.lax.bitcast_convert_type(kp_ct, cfg.act_dtype)
+                vpl = jax.lax.bitcast_convert_type(vp_ct, cfg.act_dtype)
+                ok_page = jnp.ones((B * P,), bool)
+            ok_page = ok_page.reshape(B, P)
+            if self.pool.sealed and self.open_pages:
+                # by construction only each lane's TAIL page can be OPEN
+                # (full pages close, later pages are empty), so the
+                # slice-tag path runs on one page per lane, not B*P: its
+                # verdict overrides the whole-page check exactly where the
+                # trusted state says OPEN
+                tail_idx = jnp.clip(seq_lens // ps, 0, P - 1)  # [B]
+                tail_pp = jnp.take_along_axis(page_tables, tail_idx[:, None],
+                                              axis=1)[:, 0]
+                ok_open = jax.vmap(
+                    lambda pp: kv_pager.verify_open_page(
+                        k_ct[pp], v_ct[pp], k_stags[pp], v_stags[pp],
+                        keys[pp], nonces[pp], fill[pp],
+                        self.pool.chunk_words)
+                )(tail_pp)
+                ok_closed_tail = jnp.take_along_axis(
+                    ok_page, tail_idx[:, None], axis=1)[:, 0]
+                ok_tail = jnp.where(open_flags[tail_pp], ok_open,
+                                    ok_closed_tail)
+                ok_page = ok_page.at[jnp.arange(B), tail_idx].set(ok_tail)
+            # only pages holding valid positions count toward a slot's
+            # verdict, and idle lanes (scratch-page walks over garbage)
+            # never fail
+            page_used = (jnp.arange(P)[None, :] * ps) < seq_lens[:, None]
+            ok_seq = (jnp.all(ok_page | ~page_used, axis=1) & okp) | ~active
 
-        # [B*P, L, ps, K, hd] -> [L, B, T, K, hd]
-        kcache = kpl.reshape(B, P, Lc, ps, K, hd).transpose(
-            2, 0, 1, 3, 4, 5).reshape(Lc, B, T, K, hd)
-        vcache = vpl.reshape(B, P, Lc, ps, K, hd).transpose(
-            2, 0, 1, 3, 4, 5).reshape(Lc, B, T, K, hd)
-        # slots beyond each sequence's length hold keystream noise — zero them
-        tmask = (jnp.arange(T)[None, :] < seq_lens[:, None])      # [B, T]
-        kcache = jnp.where(tmask[None, :, :, None, None], kcache,
-                           jnp.zeros((), cfg.act_dtype))
-        vcache = jnp.where(tmask[None, :, :, None, None], vcache,
-                           jnp.zeros((), cfg.act_dtype))
+        with jax.named_scope("kv_gather"):
+            # [B*P, L, ps, K, hd] -> [L, B, T, K, hd]
+            kcache = kpl.reshape(B, P, Lc, ps, K, hd).transpose(
+                2, 0, 1, 3, 4, 5).reshape(Lc, B, T, K, hd)
+            vcache = vpl.reshape(B, P, Lc, ps, K, hd).transpose(
+                2, 0, 1, 3, 4, 5).reshape(Lc, B, T, K, hd)
+            # slots beyond each sequence's length hold keystream noise —
+            # zero them
+            tmask = (jnp.arange(T)[None, :] < seq_lens[:, None])  # [B, T]
+            kcache = jnp.where(tmask[None, :, :, None, None], kcache,
+                               jnp.zeros((), cfg.act_dtype))
+            vcache = jnp.where(tmask[None, :, :, None, None], vcache,
+                               jnp.zeros((), cfg.act_dtype))
         return kcache, vcache, ok_seq
 
     # -- chunked batched prefill -----------------------------------------
@@ -274,114 +286,124 @@ class PagedEngine:
         n_cp = C // ps
         Lc, K, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
 
-        params, okp = unseal_params(params_in, self.channel.jkey,
-                                    self._sealed_params)
+        with jax.named_scope("weights_unseal"):
+            params, okp = unseal_params(params_in, self.channel.jkey,
+                                        self._sealed_params)
         kcache, vcache, ok_seq = self._gather_unseal(
             pool_arrays, page_tables, start, active, okp)
 
-        x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.act_dtype)
-        positions = start[:, None] + jnp.arange(C)[None, :]       # [B, C]
+        with jax.named_scope("model"):
+            x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.act_dtype)
+            positions = start[:, None] + jnp.arange(C)[None, :]  # [B, C]
 
-        def block(carry, xs):
-            (xc,) = carry
-            lp, kc, vc = xs                                       # kc [B,T,K,hd]
-            h = L.rms_norm(xc, lp["ln1"], cfg.norm_eps)
-            q, kn, vn = L.project_qkv(lp["attn"], cfg, h, positions)
-            # extend the cache by C rows before inserting the chunk:
-            # ``start`` is page-aligned but need not be C-aligned (a prefix
-            # cache hit resumes at the shared floor), and an insert whose
-            # window overruns T would be silently CLAMPED to fit — landing
-            # the chunk at the wrong rows.  The C extension keeps any
-            # start <= T in bounds; rows past the last valid query are
-            # causally masked, so the padding never reaches the output.
-            ext = jnp.zeros((B, C) + kc.shape[2:], kc.dtype)
-            kc2 = jax.vmap(
-                lambda c, n, p: jax.lax.dynamic_update_slice(c, n, (p, 0, 0))
-            )(jnp.concatenate([kc, ext], axis=1), kn, start)
-            vc2 = jax.vmap(
-                lambda c, n, p: jax.lax.dynamic_update_slice(c, n, (p, 0, 0))
-            )(jnp.concatenate([vc, ext], axis=1), vn, start)
-            a = L.gqa_attention(q, kc2, vc2, causal=True,
-                                q_block=cfg.q_block, base_pos=start)
-            xc = xc + L.attn_out(lp["attn"], a, B, C)
-            h2 = L.rms_norm(xc, lp["ln2"], cfg.norm_eps)
-            xc = xc + L.swiglu(lp["mlp"], h2)
-            return (xc,), (kn, vn)
+            def block(carry, xs):
+                (xc,) = carry
+                lp, kc, vc = xs  # kc [B,T,K,hd]
+                h = L.rms_norm(xc, lp["ln1"], cfg.norm_eps)
+                q, kn, vn = L.project_qkv(lp["attn"], cfg, h, positions)
+                # extend the cache by C rows before inserting the chunk:
+                # ``start`` is page-aligned but need not be C-aligned (a
+                # prefix cache hit resumes at the shared floor), and an
+                # insert whose window overruns T would be silently CLAMPED
+                # to fit — landing the chunk at the wrong rows.  The C
+                # extension keeps any start <= T in bounds; rows past the
+                # last valid query are causally masked, so the padding
+                # never reaches the output.
+                ext = jnp.zeros((B, C) + kc.shape[2:], kc.dtype)
+                kc2 = jax.vmap(
+                    lambda c, n, p: jax.lax.dynamic_update_slice(
+                        c, n, (p, 0, 0))
+                )(jnp.concatenate([kc, ext], axis=1), kn, start)
+                vc2 = jax.vmap(
+                    lambda c, n, p: jax.lax.dynamic_update_slice(
+                        c, n, (p, 0, 0))
+                )(jnp.concatenate([vc, ext], axis=1), vn, start)
+                a = L.gqa_attention(q, kc2, vc2, causal=True,
+                                    q_block=cfg.q_block, base_pos=start)
+                xc = xc + L.attn_out(lp["attn"], a, B, C)
+                h2 = L.rms_norm(xc, lp["ln2"], cfg.norm_eps)
+                xc = xc + L.swiglu(lp["mlp"], h2)
+                return (xc,), (kn, vn)
 
-        (x,), (nk, nv) = jax.lax.scan(
-            block, (x,), (params["layers"], kcache, vcache))
+            (x,), (nk, nv) = jax.lax.scan(
+                block, (x,), (params["layers"], kcache, vcache))
 
-        # first-token logits for lanes whose prompt completes in this chunk
-        x_last = jax.vmap(
-            lambda xb, v: jax.lax.dynamic_slice(xb, (v - 1, 0),
-                                                (1, xb.shape[-1]))
-        )(x, valid)                                               # [B, 1, D]
-        logits = transformer.logits_of(params, cfg, x_last)[:, 0]  # [B, V]
-        logits = jnp.where(ok_seq[:, None], logits, jnp.nan)
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        tok = jnp.where(ok_seq, tok, TOKEN_POISON)
-        tok = jnp.where(active, tok, 0)
+            # first-token logits for lanes whose prompt completes in this chunk
+            x_last = jax.vmap(
+                lambda xb, v: jax.lax.dynamic_slice(xb, (v - 1, 0),
+                                                    (1, xb.shape[-1]))
+            )(x, valid)  # [B, 1, D]
+            logits = transformer.logits_of(params, cfg, x_last)[:, 0]  # [B, V]
+            logits = jnp.where(ok_seq[:, None], logits, jnp.nan)
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            tok = jnp.where(ok_seq, tok, TOKEN_POISON)
+            tok = jnp.where(active, tok, 0)
 
-        # -- write back the chunk's pages -------------------------------
-        nk_b = nk.transpose(1, 0, 2, 3, 4)                        # [B,L,C,K,hd]
-        nv_b = nv.transpose(1, 0, 2, 3, 4)
-        kp = nk_b.reshape(B, Lc, n_cp, ps, K, hd).transpose(0, 2, 1, 3, 4, 5)
-        vp = nv_b.reshape(B, Lc, n_cp, ps, K, hd).transpose(0, 2, 1, 3, 4, 5)
-        kp_f = kp.reshape(B * n_cp, Lc, ps, K, hd)
-        vp_f = vp.reshape(B * n_cp, Lc, ps, K, hd)
-        cp_j = jnp.arange(n_cp)[None, :]                          # [1, n_cp]
-        lpid = jnp.clip(start[:, None] // ps + cp_j, 0, P - 1)
-        ppid = jnp.take_along_axis(page_tables, lpid, axis=1)     # [B, n_cp]
-        vip = jnp.clip(valid[:, None] - cp_j * ps, 0, ps)         # [B, n_cp]
-        written = (vip > 0) & active[:, None]
-        # unwritten chunk pages (prompt ended earlier) divert to scratch
-        target = jnp.where(written, ppid, kv_pager.SCRATCH_PAGE)
-        tgt = target.reshape(-1)
-        if self.pool.sealed:
-            kct, vct, ktags, vtags = jax.vmap(
-                lambda k_, v_, kw, nn: kv_pager.seal_page(
-                    k_, v_, kw, nn, self.pool.chunk_words)
-            )(kp_f, vp_f, keys[tgt], nonces[tgt])
-        else:
-            kct, vct = jax.vmap(kv_pager.bitcast_page)(kp_f, vp_f)
-            ktags = jnp.zeros((B * n_cp, self.pool.n_tags), jnp.uint32)
-            vtags = jnp.zeros((B * n_cp, self.pool.n_tags), jnp.uint32)
-        k_ct = k_ct.at[tgt].set(kct)
-        v_ct = v_ct.at[tgt].set(vct)
-        k_tags = k_tags.at[tgt].set(ktags)
-        v_tags = v_tags.at[tgt].set(vtags)
-        if self.open_pages:
-            # the page containing a prompt's boundary stays OPEN (decode
-            # appends into it); full pages close with their chunk tags
-            is_boundary = (vip > 0) & (vip < ps) & active[:, None]
-            open_flags = open_flags.at[tgt].set(is_boundary.reshape(-1))
-            fill = fill.at[tgt].set(
-                jnp.where(is_boundary, vip, 0).reshape(-1))
+        with jax.named_scope("kv_seal"):
+            # -- write back the chunk's pages -------------------------------
+            nk_b = nk.transpose(1, 0, 2, 3, 4)  # [B,L,C,K,hd]
+            nv_b = nv.transpose(1, 0, 2, 3, 4)
+            kp = nk_b.reshape(B, Lc, n_cp, ps, K, hd).transpose(
+                0, 2, 1, 3, 4, 5)
+            vp = nv_b.reshape(B, Lc, n_cp, ps, K, hd).transpose(
+                0, 2, 1, 3, 4, 5)
+            kp_f = kp.reshape(B * n_cp, Lc, ps, K, hd)
+            vp_f = vp.reshape(B * n_cp, Lc, ps, K, hd)
+            cp_j = jnp.arange(n_cp)[None, :]  # [1, n_cp]
+            lpid = jnp.clip(start[:, None] // ps + cp_j, 0, P - 1)
+            ppid = jnp.take_along_axis(page_tables, lpid, axis=1)  # [B, n_cp]
+            vip = jnp.clip(valid[:, None] - cp_j * ps, 0, ps)  # [B, n_cp]
+            written = (vip > 0) & active[:, None]
+            # unwritten chunk pages (prompt ended earlier) divert to scratch
+            target = jnp.where(written, ppid, kv_pager.SCRATCH_PAGE)
+            tgt = target.reshape(-1)
             if self.pool.sealed:
-                bj = jnp.clip(valid // ps, 0, n_cp - 1)           # [B]
-                has_b = ((valid % ps) > 0) & active
-                b_tgt = jnp.where(
-                    has_b,
-                    jax.vmap(lambda t, j: t[j])(target, bj),
-                    kv_pager.SCRATCH_PAGE)
-                kct_p = kct.reshape(B, n_cp, Lc, ps, K, hd)
-                vct_p = vct.reshape(B, n_cp, Lc, ps, K, hd)
-                kct_b = jax.vmap(lambda c, j: c[j])(kct_p, bj)
-                vct_b = jax.vmap(lambda c, j: c[j])(vct_p, bj)
-                kst, vst = jax.vmap(
-                    lambda kc, vc, kw, nn: kv_pager.page_slot_tags(
-                        kc, vc, kw, nn, self.pool.chunk_words)
-                )(kct_b, vct_b, keys[b_tgt], nonces[b_tgt])
-                k_stags = k_stags.at[b_tgt].set(kst)
-                v_stags = v_stags.at[b_tgt].set(vst)
+                kct, vct, ktags, vtags = jax.vmap(
+                    lambda k_, v_, kw, nn: kv_pager.seal_page(
+                        k_, v_, kw, nn, self.pool.chunk_words)
+                )(kp_f, vp_f, keys[tgt], nonces[tgt])
+            else:
+                kct, vct = jax.vmap(kv_pager.bitcast_page)(kp_f, vp_f)
+                ktags = jnp.zeros((B * n_cp, self.pool.n_tags), jnp.uint32)
+                vtags = jnp.zeros((B * n_cp, self.pool.n_tags), jnp.uint32)
+            k_ct = k_ct.at[tgt].set(kct)
+            v_ct = v_ct.at[tgt].set(vct)
+            k_tags = k_tags.at[tgt].set(ktags)
+            v_tags = v_tags.at[tgt].set(vtags)
+            if self.open_pages:
+                # the page containing a prompt's boundary stays OPEN (decode
+                # appends into it); full pages close with their chunk tags
+                is_boundary = (vip > 0) & (vip < ps) & active[:, None]
+                open_flags = open_flags.at[tgt].set(is_boundary.reshape(-1))
+                fill = fill.at[tgt].set(
+                    jnp.where(is_boundary, vip, 0).reshape(-1))
+                if self.pool.sealed:
+                    bj = jnp.clip(valid // ps, 0, n_cp - 1)  # [B]
+                    has_b = ((valid % ps) > 0) & active
+                    b_tgt = jnp.where(
+                        has_b,
+                        jax.vmap(lambda t, j: t[j])(target, bj),
+                        kv_pager.SCRATCH_PAGE)
+                    kct_p = kct.reshape(B, n_cp, Lc, ps, K, hd)
+                    vct_p = vct.reshape(B, n_cp, Lc, ps, K, hd)
+                    kct_b = jax.vmap(lambda c, j: c[j])(kct_p, bj)
+                    vct_b = jax.vmap(lambda c, j: c[j])(vct_p, bj)
+                    kst, vst = jax.vmap(
+                        lambda kc, vc, kw, nn: kv_pager.page_slot_tags(
+                            kc, vc, kw, nn, self.pool.chunk_words)
+                    )(kct_b, vct_b, keys[b_tgt], nonces[b_tgt])
+                    k_stags = k_stags.at[b_tgt].set(kst)
+                    v_stags = v_stags.at[b_tgt].set(vst)
         return tok, ok_seq, (k_ct, v_ct, k_tags, v_tags, k_stags, v_stags,
                              nonces, keys, open_flags, fill)
 
-    def chunk_prefill(self, tokens, start, valid, active, page_tables):
+    def chunk_prefill(self, tokens, start, valid, active, page_tables,
+                      rid: str = ""):
         """Host-side wrapper for one batched prefill-chunk step.
 
         Returns (tok [B], ok [B]): ``tok`` is each lane's first generated
         token, meaningful only for lanes whose prompt completed this chunk.
+        ``rid`` names the lanes' requests on the trace span.
         """
         active = np.asarray(active, bool)
         valid = np.asarray(valid, np.int32)
@@ -389,7 +411,7 @@ class PagedEngine:
         pt_np = np.asarray(page_tables, np.int32)
         n_lanes = int(active.sum())
         with self.tracer.span("engine.chunk_prefill", cat="engine",
-                              args={"lanes": n_lanes}), \
+                              args={"rid": rid, "lanes": n_lanes}), \
                 self.profiler.phase("prefill") as ph:
             tok, ok, arrays = self._chunk_prefill(
                 self.params, jnp.asarray(tokens, jnp.int32),
@@ -411,19 +433,20 @@ class PagedEngine:
     def _close_impl(self, pool_arrays, page):
         (k_ct, v_ct, k_tags, v_tags, k_stags, v_stags, nonces, keys,
          open_flags, fill) = pool_arrays
-        kct2, vct2, ktags, vtags, ok = kv_pager.close_page(
-            k_ct[page], v_ct[page], k_stags[page], v_stags[page],
-            keys[page], nonces[page], fill[page], self.cfg.act_dtype,
-            self.pool.chunk_words)
-        k_ct = k_ct.at[page].set(kct2)
-        v_ct = v_ct.at[page].set(vct2)
-        k_tags = k_tags.at[page].set(ktags)
-        v_tags = v_tags.at[page].set(vtags)
-        k_stags = k_stags.at[page].set(0)
-        v_stags = v_stags.at[page].set(0)
-        nonces = nonces.at[page].add(1)
-        open_flags = open_flags.at[page].set(False)
-        fill = fill.at[page].set(0)
+        with jax.named_scope("kv_seal"):
+            kct2, vct2, ktags, vtags, ok = kv_pager.close_page(
+                k_ct[page], v_ct[page], k_stags[page], v_stags[page],
+                keys[page], nonces[page], fill[page], self.cfg.act_dtype,
+                self.pool.chunk_words)
+            k_ct = k_ct.at[page].set(kct2)
+            v_ct = v_ct.at[page].set(vct2)
+            k_tags = k_tags.at[page].set(ktags)
+            v_tags = v_tags.at[page].set(vtags)
+            k_stags = k_stags.at[page].set(0)
+            v_stags = v_stags.at[page].set(0)
+            nonces = nonces.at[page].add(1)
+            open_flags = open_flags.at[page].set(False)
+            fill = fill.at[page].set(0)
         return ok, (k_ct, v_ct, k_tags, v_tags, k_stags, v_stags, nonces,
                     keys, open_flags, fill)
 
@@ -627,110 +650,118 @@ class PagedEngine:
         ps = self.pool.page_size
         Lc, K, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
 
-        params, okp = unseal_params(params_in, self.channel.jkey,
-                                    self._sealed_params)
+        with jax.named_scope("weights_unseal"):
+            params, okp = unseal_params(params_in, self.channel.jkey,
+                                        self._sealed_params)
         kcache, vcache, ok_seq = self._gather_unseal(
             pool_arrays, page_tables, seq_lens, active, okp)
 
-        x = jnp.take(params["embed"], tokens[:, None],
-                     axis=0).astype(cfg.act_dtype)                # [B, 1, D]
-        positions = seq_lens[:, None]                             # [B, 1]
+        with jax.named_scope("model"):
+            x = jnp.take(params["embed"], tokens[:, None],
+                         axis=0).astype(cfg.act_dtype)  # [B, 1, D]
+            positions = seq_lens[:, None]  # [B, 1]
 
-        def block(carry, xs):
-            (xc,) = carry
-            lp, kc, vc = xs                                       # kc [B,T,K,hd]
-            h = L.rms_norm(xc, lp["ln1"], cfg.norm_eps)
-            q, kn, vn = L.project_qkv(lp["attn"], cfg, h, positions)
-            kc2 = jax.vmap(
-                lambda c, n, p: jax.lax.dynamic_update_slice(c, n, (p, 0, 0))
-            )(kc, kn, seq_lens)
-            vc2 = jax.vmap(
-                lambda c, n, p: jax.lax.dynamic_update_slice(c, n, (p, 0, 0))
-            )(vc, vn, seq_lens)
-            a = L.gqa_attention(q, kc2, vc2, causal=False,
-                                t_valid=seq_lens + 1)
-            xc = xc + L.attn_out(lp["attn"], a, B, 1)
-            h2 = L.rms_norm(xc, lp["ln2"], cfg.norm_eps)
-            xc = xc + L.swiglu(lp["mlp"], h2)
-            # open mode writes back just the new slot; legacy needs the
-            # full updated cache to re-seal the whole tail page
-            ys = (kn, vn) if self.open_pages else (kc2, vc2)
-            return (xc,), ys
+            def block(carry, xs):
+                (xc,) = carry
+                lp, kc, vc = xs  # kc [B,T,K,hd]
+                h = L.rms_norm(xc, lp["ln1"], cfg.norm_eps)
+                q, kn, vn = L.project_qkv(lp["attn"], cfg, h, positions)
+                kc2 = jax.vmap(
+                    lambda c, n, p: jax.lax.dynamic_update_slice(
+                        c, n, (p, 0, 0))
+                )(kc, kn, seq_lens)
+                vc2 = jax.vmap(
+                    lambda c, n, p: jax.lax.dynamic_update_slice(
+                        c, n, (p, 0, 0))
+                )(vc, vn, seq_lens)
+                a = L.gqa_attention(q, kc2, vc2, causal=False,
+                                    t_valid=seq_lens + 1)
+                xc = xc + L.attn_out(lp["attn"], a, B, 1)
+                h2 = L.rms_norm(xc, lp["ln2"], cfg.norm_eps)
+                xc = xc + L.swiglu(lp["mlp"], h2)
+                # open mode writes back just the new slot; legacy needs the
+                # full updated cache to re-seal the whole tail page
+                ys = (kn, vn) if self.open_pages else (kc2, vc2)
+                return (xc,), ys
 
-        (x,), (nk, nv) = jax.lax.scan(
-            block, (x,), (params["layers"], kcache, vcache))
+            (x,), (nk, nv) = jax.lax.scan(
+                block, (x,), (params["layers"], kcache, vcache))
 
-        logits = transformer.logits_of(params, cfg, x)[:, 0]      # [B, V]
-        logits = jnp.where(ok_seq[:, None], logits, jnp.nan)
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        tok = jnp.where(ok_seq, tok, TOKEN_POISON)
-        tok = jnp.where(active, tok, 0)                           # idle lanes
+            logits = transformer.logits_of(params, cfg, x)[:, 0]  # [B, V]
+            logits = jnp.where(ok_seq[:, None], logits, jnp.nan)
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            tok = jnp.where(ok_seq, tok, TOKEN_POISON)
+            tok = jnp.where(active, tok, 0)  # idle lanes
 
-        if self.open_pages:
-            # -- write-back: seal ONLY the new token slot (§3.4) --------
-            # nk: [L, B, 1, K, hd] new-token slices from the scan
-            slot = seq_lens % ps                                  # [B]
-            k_slot = nk[:, :, 0].transpose(1, 0, 2, 3)            # [B,L,K,hd]
-            v_slot = nv[:, :, 0].transpose(1, 0, 2, 3)
-            keys_w = keys[write_pp]
-            nonce_w = nonces[write_pp]                            # no bump
-            if self.pool.sealed:
-                kct_s, vct_s, ktag, vtag = jax.vmap(
-                    lambda k_, v_, kw, nn, sl: kv_pager.seal_slot(
-                        k_, v_, kw, nn, sl, ps, self.pool.chunk_words)
-                )(k_slot, v_slot, keys_w, nonce_w, slot)
-                k_stags = k_stags.at[write_pp, slot].set(ktag)
-                v_stags = v_stags.at[write_pp, slot].set(vtag)
+        with jax.named_scope("kv_seal"):
+            if self.open_pages:
+                # -- write-back: seal ONLY the new token slot (§3.4) --------
+                # nk: [L, B, 1, K, hd] new-token slices from the scan
+                slot = seq_lens % ps  # [B]
+                k_slot = nk[:, :, 0].transpose(1, 0, 2, 3)  # [B,L,K,hd]
+                v_slot = nv[:, :, 0].transpose(1, 0, 2, 3)
+                keys_w = keys[write_pp]
+                nonce_w = nonces[write_pp]  # no bump
+                if self.pool.sealed:
+                    kct_s, vct_s, ktag, vtag = jax.vmap(
+                        lambda k_, v_, kw, nn, sl: kv_pager.seal_slot(
+                            k_, v_, kw, nn, sl, ps, self.pool.chunk_words)
+                    )(k_slot, v_slot, keys_w, nonce_w, slot)
+                    k_stags = k_stags.at[write_pp, slot].set(ktag)
+                    v_stags = v_stags.at[write_pp, slot].set(vtag)
+                else:
+                    udt = cipher.uint_dtype_for(cfg.act_dtype)
+                    kct_s = jax.lax.bitcast_convert_type(k_slot, udt)
+                    vct_s = jax.lax.bitcast_convert_type(v_slot, udt)
+                # idle lanes hit (SCRATCH_PAGE, slot 0); live lanes hold
+                # distinct pages, so no meaningful scatter collisions
+                k_ct = k_ct.at[write_pp, :, slot].set(kct_s)
+                v_ct = v_ct.at[write_pp, :, slot].set(vct_s)
+                fill = fill.at[write_pp].set(slot + 1)
             else:
-                udt = cipher.uint_dtype_for(cfg.act_dtype)
-                kct_s = jax.lax.bitcast_convert_type(k_slot, udt)
-                vct_s = jax.lax.bitcast_convert_type(v_slot, udt)
-            # idle lanes hit (SCRATCH_PAGE, slot 0); live lanes hold
-            # distinct pages, so no meaningful scatter collisions
-            k_ct = k_ct.at[write_pp, :, slot].set(kct_s)
-            v_ct = v_ct.at[write_pp, :, slot].set(vct_s)
-            fill = fill.at[write_pp].set(slot + 1)
-        else:
-            # -- legacy write-back: reseal the whole tail page ----------
-            page_off = (seq_lens // ps) * ps                      # [B]
-            nk_b = nk.transpose(1, 0, 2, 3, 4)                    # [B,L,T,K,hd]
-            nv_b = nv.transpose(1, 0, 2, 3, 4)
-            k_new = jax.vmap(
-                lambda c, o: jax.lax.dynamic_slice(c, (0, o, 0, 0),
-                                                   (Lc, ps, K, hd))
-            )(nk_b, page_off)                                     # [B,L,ps,K,hd]
-            v_new = jax.vmap(
-                lambda c, o: jax.lax.dynamic_slice(c, (0, o, 0, 0),
-                                                   (Lc, ps, K, hd))
-            )(nv_b, page_off)
-            keys_w = keys[write_pp]                               # [B, 2]
-            nonce_w = nonces[write_pp] + jnp.uint32(1)            # freshness
-            if self.pool.sealed:
-                kct_n, vct_n, ktags_n, vtags_n = jax.vmap(
-                    lambda k_, v_, kw, nn: kv_pager.seal_page(
-                        k_, v_, kw, nn, self.pool.chunk_words)
-                )(k_new, v_new, keys_w, nonce_w)
-            else:
-                kct_n, vct_n = jax.vmap(kv_pager.bitcast_page)(k_new, v_new)
-                ktags_n = jnp.zeros((B, self.pool.n_tags), jnp.uint32)
-                vtags_n = jnp.zeros((B, self.pool.n_tags), jnp.uint32)
-            # idle lanes target SCRATCH_PAGE; live lanes hold distinct
-            # pages, so the scatter has no meaningful index collisions.
-            k_ct = k_ct.at[write_pp].set(kct_n)
-            v_ct = v_ct.at[write_pp].set(vct_n)
-            k_tags = k_tags.at[write_pp].set(ktags_n)
-            v_tags = v_tags.at[write_pp].set(vtags_n)
-            nonces = nonces.at[write_pp].set(nonce_w)
+                # -- legacy write-back: reseal the whole tail page ----------
+                page_off = (seq_lens // ps) * ps  # [B]
+                nk_b = nk.transpose(1, 0, 2, 3, 4)  # [B,L,T,K,hd]
+                nv_b = nv.transpose(1, 0, 2, 3, 4)
+                k_new = jax.vmap(
+                    lambda c, o: jax.lax.dynamic_slice(c, (0, o, 0, 0),
+                                                       (Lc, ps, K, hd))
+                )(nk_b, page_off)  # [B,L,ps,K,hd]
+                v_new = jax.vmap(
+                    lambda c, o: jax.lax.dynamic_slice(c, (0, o, 0, 0),
+                                                       (Lc, ps, K, hd))
+                )(nv_b, page_off)
+                keys_w = keys[write_pp]  # [B, 2]
+                nonce_w = nonces[write_pp] + jnp.uint32(1)  # freshness
+                if self.pool.sealed:
+                    kct_n, vct_n, ktags_n, vtags_n = jax.vmap(
+                        lambda k_, v_, kw, nn: kv_pager.seal_page(
+                            k_, v_, kw, nn, self.pool.chunk_words)
+                    )(k_new, v_new, keys_w, nonce_w)
+                else:
+                    kct_n, vct_n = jax.vmap(kv_pager.bitcast_page)(
+                        k_new, v_new)
+                    ktags_n = jnp.zeros((B, self.pool.n_tags), jnp.uint32)
+                    vtags_n = jnp.zeros((B, self.pool.n_tags), jnp.uint32)
+                # idle lanes target SCRATCH_PAGE; live lanes hold distinct
+                # pages, so the scatter has no meaningful index collisions.
+                k_ct = k_ct.at[write_pp].set(kct_n)
+                v_ct = v_ct.at[write_pp].set(vct_n)
+                k_tags = k_tags.at[write_pp].set(ktags_n)
+                v_tags = v_tags.at[write_pp].set(vtags_n)
+                nonces = nonces.at[write_pp].set(nonce_w)
         return tok, ok_seq, (k_ct, v_ct, k_tags, v_tags, k_stags, v_stags,
                              nonces, keys, open_flags, fill)
 
-    def decode_step(self, tokens, seq_lens, active, page_tables, write_pp):
-        """Host-side wrapper: threads the pool through the jitted body."""
+    def decode_step(self, tokens, seq_lens, active, page_tables, write_pp,
+                    rid: str = ""):
+        """Host-side wrapper: threads the pool through the jitted body.
+        ``rid`` names the lanes' requests on the trace span."""
         active_np = np.asarray(active, bool)
         wp_np = np.asarray(write_pp, np.int32)
         n_act = int(active_np.sum())
         with self.tracer.span("engine.decode_step", cat="engine",
-                              args={"lanes": n_act}), \
+                              args={"rid": rid, "lanes": n_act}), \
                 self.profiler.phase("decode") as ph:
             tok, ok, arrays = self._decode(
                 self.params, jnp.asarray(tokens, jnp.int32),

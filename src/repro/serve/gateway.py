@@ -84,9 +84,12 @@ class SecureGateway:
         sec = (SecurityConfig() if security == "trusted"
                else SecurityConfig.off())
         self.store = store if store is not None else SealedStore()
+        self.tracer = Tracer(enabled=trace)
+        self.tracer.name_process("secure-gateway")
+        self.tracer.name_thread(TID_ENGINE, "engine")
         self.sessions = SessionManager(device_id, config=sec,
                                        rotate_every=rotate_every,
-                                       store=self.store)
+                                       store=self.store, tracer=self.tracer)
         provider = self.sessions.register(PROVIDER).channel
         # the audit chain keys off the provider session (the same root of
         # trust that MACs launch descriptors); it must exist before any
@@ -95,9 +98,6 @@ class SecureGateway:
         self.audit = AuditLog(provider.key_bytes)
         self.sessions.attach_audit(self.audit)
         self.store.audit = self.audit
-        self.tracer = Tracer(enabled=trace)
-        self.tracer.name_process("secure-gateway")
-        self.tracer.name_thread(TID_ENGINE, "engine")
         self.registry = MetricsRegistry()
         self.profiler = Profiler(registry=self.registry, tracer=self.tracer,
                                  chunk_words=chunk_words)
@@ -109,7 +109,7 @@ class SecureGateway:
             n_kv_heads=cfg.n_kv_heads, hd=cfg.hd, dtype=cfg.act_dtype,
             chunk_words=chunk_words, sealed=sealed, open_pages=open_pages,
             metrics=self.registry, audit=self.audit,
-            profiler=self.profiler)
+            profiler=self.profiler, tracer=self.tracer)
         self.engine = PagedEngine(
             cfg=cfg, params=params_dev, channel=provider, pool=self.pool,
             max_slots=max_slots, max_pages=max_pages_per_seq,
@@ -185,9 +185,12 @@ class SecureGateway:
         priority: higher classes may preempt running lower-class requests
         (their sealed KV swaps out to the store and back — see scheduler).
         """
-        self.register_tenant(tenant_id)
-        return self.scheduler.submit(tenant_id, np.asarray(prompt, np.int32),
-                                     max_new, priority=priority)
+        with self.tracer.span("gateway.submit", cat="serve",
+                              args={"tenant": tenant_id}):
+            self.register_tenant(tenant_id)
+            return self.scheduler.submit(
+                tenant_id, np.asarray(prompt, np.int32), max_new,
+                priority=priority)
 
     def step(self) -> dict:
         """Advance the engine one scheduling step (admit + decode + evict)."""
@@ -215,7 +218,8 @@ class SecureGateway:
                                   tenant=req.tenant_id).inc()
         self._nsteps += 1
         if self.monitor is not None:
-            self._monitor_observe()
+            with self.tracer.span("gateway.monitor", cat="serve"):
+                self._monitor_observe()
         return events
 
     # -- monitor sample + action handlers --------------------------------
@@ -334,6 +338,7 @@ class SecureGateway:
             "p50_token_ms": lat.percentile(0.50),
             "p95_token_ms": lat.percentile(0.95),
             "mean_ttft_ms": sched._h_ttft.mean,
+            "queue_wait_p90_ms": sched.queue_wait_percentile(0.90),
             "preempted_ttft_ms": sched._h_pre_ttft.mean,
             "preempted_requests": sched._h_pre_ttft.count,
             "swap_outs": swaps["swap_outs"],

@@ -61,7 +61,7 @@ import numpy as np
 
 from ..core import cipher, mac
 from ..core import sealed as sealed_guard
-from ..obs import MetricsRegistry, StatsView
+from ..obs import MetricsRegistry, StatsView, Tracer
 
 # data-plane lane separation: k pages, v pages and page MACs never share a
 # (key, nonce) space even though all three derive from one tenant session key.
@@ -338,8 +338,11 @@ class PagedKVPool:
                                 # from the same note_* call sites (and with
                                 # the same byte formulas) as _c_sealed, so
                                 # per-bucket ledger sums reconcile exactly
+    tracer: object = None       # obs.Tracer for the pool.alloc/free spans
 
     def __post_init__(self):
+        if self.tracer is None:
+            self.tracer = Tracer(enabled=False)
         shape = (self.n_pages, self.n_layers, self.page_size,
                  self.n_kv_heads, self.hd)
         udt = cipher.uint_dtype_for(self.dtype)
@@ -474,15 +477,17 @@ class PagedKVPool:
         if n > len(self._free):
             self._c_alloc_failures.inc()
             raise PoolExhausted(f"need {n} pages, {len(self._free)} free")
-        pages = [self._free.popleft() for _ in range(n)]
-        idx = jnp.asarray(pages, jnp.int32)
-        kw = jnp.broadcast_to(jnp.asarray(key_words, jnp.uint32), (n, 2))
-        self.keys = self.keys.at[idx].set(kw)
-        self.nonces = self.nonces.at[idx].set(
-            jnp.asarray(nonces, jnp.uint32))
-        if self.open_pages:
-            self.open_flags = self.open_flags.at[idx].set(True)
-            self.fill = self.fill.at[idx].set(0)
+        with self.tracer.span("pool.alloc", cat="pool",
+                              args={"tenant": owner, "pages": n}):
+            pages = [self._free.popleft() for _ in range(n)]
+            idx = jnp.asarray(pages, jnp.int32)
+            kw = jnp.broadcast_to(jnp.asarray(key_words, jnp.uint32), (n, 2))
+            self.keys = self.keys.at[idx].set(kw)
+            self.nonces = self.nonces.at[idx].set(
+                jnp.asarray(nonces, jnp.uint32))
+            if self.open_pages:
+                self.open_flags = self.open_flags.at[idx].set(True)
+                self.fill = self.fill.at[idx].set(0)
         for i, p in enumerate(pages):
             self._owner[p] = owner
             self._nonce_guard[p] = sealed_guard.NonceSpanGuard(
@@ -520,15 +525,17 @@ class PagedKVPool:
             raise ValueError(
                 f"free() on shared pages {shared} — use unmap_shared / "
                 "release_shared for refcounted prefix pages")
-        idx = jnp.asarray(pages, jnp.int32)
-        self.keys = self.keys.at[idx].set(0)
-        self.nonces = self.nonces.at[idx].set(0)
-        self.k_tags = self.k_tags.at[idx].set(0)
-        self.v_tags = self.v_tags.at[idx].set(0)
-        self.k_stags = self.k_stags.at[idx].set(0)
-        self.v_stags = self.v_stags.at[idx].set(0)
-        self.open_flags = self.open_flags.at[idx].set(False)
-        self.fill = self.fill.at[idx].set(0)
+        with self.tracer.span("pool.free", cat="pool",
+                              args={"pages": len(pages)}):
+            idx = jnp.asarray(pages, jnp.int32)
+            self.keys = self.keys.at[idx].set(0)
+            self.nonces = self.nonces.at[idx].set(0)
+            self.k_tags = self.k_tags.at[idx].set(0)
+            self.v_tags = self.v_tags.at[idx].set(0)
+            self.k_stags = self.k_stags.at[idx].set(0)
+            self.v_stags = self.v_stags.at[idx].set(0)
+            self.open_flags = self.open_flags.at[idx].set(False)
+            self.fill = self.fill.at[idx].set(0)
         for p in pages:
             self._owner.pop(p, None)
             self._nonce_guard.pop(p, None)

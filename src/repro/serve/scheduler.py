@@ -41,7 +41,7 @@ from collections import deque
 import numpy as np
 
 from ..core import channel as channel_lib
-from ..obs import StatsView, Tracer, request_tid
+from ..obs import Histogram, StatsView, Tracer, request_tid
 from ..store import SealedStore, StoreError, choose_victim
 from .engine import TOKEN_POISON, PagedEngine
 from .kv_pager import SCRATCH_PAGE, PagedKVPool
@@ -72,6 +72,7 @@ class Request:
     pages: list = dataclasses.field(default_factory=list)
     prefill_pos: int = 0            # prompt tokens already in the cache
     t_submit: float = 0.0
+    t_admit: float = 0.0            # first admission (0 = never admitted)
     t_first: float = 0.0            # first-token (prefill) completion time
     t_last: float = 0.0             # last progress (token / admission) time
     t_done: float = 0.0
@@ -101,6 +102,12 @@ class Request:
     @property
     def finished(self) -> bool:
         return self.status in ("done", "poisoned", "quarantined")
+
+
+def lane_args(lanes: list[Request]) -> dict:
+    """Span args naming a step's lanes: ``rid`` is their request ids
+    joined by spaces (a profiler annotation takes scalar values)."""
+    return {"rid": " ".join(str(r.rid) for r in lanes), "lanes": len(lanes)}
 
 
 class Scheduler:
@@ -155,6 +162,8 @@ class Scheduler:
         self._h_pre_ttft = reg.histogram(
             "request_preempted_ttft_ms",
             "submit -> first token for requests that were swapped out, ms")
+        self._h_queue_wait = reg.histogram(
+            "sched_queue_wait_ms", "submit -> first admission, ms")
 
     def reset(self) -> None:
         """Fresh measurement window for the scheduler's own metrics."""
@@ -164,6 +173,7 @@ class Scheduler:
             c.reset()
         self._h_ttft.reset()
         self._h_pre_ttft.reset()
+        self._h_queue_wait.reset()
 
     def _audit(self, kind: str, tenant: str | None, **detail) -> None:
         if self.audit is not None:
@@ -221,6 +231,24 @@ class Scheduler:
     @property
     def active(self) -> list[Request]:
         return [r for r in self.slots if r is not None]
+
+    def _lanes(self, status: str) -> list[Request]:
+        return [r for r in self.slots if r is not None and r.status == status]
+
+    def queue_wait_percentile(self, p: float) -> float:
+        """Nearest-rank ``p`` of the queue wait (submit to first admission,
+        ms) of the requests first admitted in this window, with every
+        request still waiting for its first admission entered at its wait
+        so far, so that a stall stays in the tail.  0.0 when there is
+        none."""
+        waits = Histogram("queue_wait_ms")
+        for v in self._h_queue_wait.values:
+            waits.observe(v)
+        now = time.monotonic()
+        for r in self.queue:
+            if not r.t_admit:
+                waits.observe((now - r.t_submit) * 1e3)
+        return waits.percentile(p)
 
     @property
     def idle(self) -> bool:
@@ -309,12 +337,18 @@ class Scheduler:
     def step(self) -> dict:
         events = {"admitted": [], "emitted": [], "finished": [],
                   "poisoned": [], "preempted": [], "resumed": []}
-        with self.tracer.span("sched.admit", cat="sched"):
+        with self.tracer.span("sched.admit", cat="sched",
+                              args={"queued": len(self.queue),
+                                    "lanes": len(self.active)}):
             self._admit(events)
-        with self.tracer.span("sched.prefill", cat="sched"):
-            self._prefill_step(events)
-        with self.tracer.span("sched.decode", cat="sched"):
-            self._decode(events)
+        lanes = self._lanes("prefilling")
+        with self.tracer.span("sched.prefill", cat="sched",
+                              args=lane_args(lanes)):
+            self._prefill_step(lanes, events)
+        lanes = self._lanes("running")
+        with self.tracer.span("sched.decode", cat="sched",
+                              args=lane_args(lanes)):
+            self._decode(lanes, events)
         return events
 
     # -- admission + preemption -----------------------------------------
@@ -372,6 +406,8 @@ class Scheduler:
                  else None)
         n_pages = self.required_pages(req)
         sess = self.sessions.get(req.tenant_id)
+        req.t_admit = time.monotonic()
+        self._h_queue_wait.observe((req.t_admit - req.t_submit) * 1e3)
         # rotation point: tenant has no sealed state in flight right now
         if (self.sessions.rotation_due(req.tenant_id)
                 and self.tenant_quiescent(req.tenant_id)):
@@ -449,16 +485,15 @@ class Scheduler:
                                     "skip_tokens": req.prefill_pos})
 
     # -- chunked batched prefill ----------------------------------------
-    def _prefill_step(self, events: dict) -> None:
-        """Advance every prefilling slot by one chunk, in one batched call.
+    def _prefill_step(self, lanes: list[Request], events: dict) -> None:
+        """Advance every prefilling slot (``lanes``) by one chunk, in one
+        batched call.
 
         A prompt shorter than the chunk completes immediately (its first
         token is recorded and it joins the decode batch this very step);
         longer prompts pay their prefill in installments so a burst of
         arrivals never serializes whole prompts in front of each other.
         """
-        lanes = [r for r in self.slots
-                 if r is not None and r.status == "prefilling"]
         if not lanes:
             return
         B, P = self.max_slots, self.max_pages
@@ -494,7 +529,8 @@ class Scheduler:
         tok, ok = launch(
             self.engine.chunk_prefill,
             {"op": "prefill_chunk_batch", "lanes": lane_desc},
-            tokens, start, valid, active, page_tables)
+            tokens, start, valid, active, page_tables,
+            lane_args(lanes)["rid"])
         self._c_prefill["chunks"].inc()
         self._c_prefill["chunk_lanes"].inc(len(lanes))
         self._c_prefill["chunk_tokens"].inc(int(
@@ -667,9 +703,7 @@ class Scheduler:
         self._evict(req)
 
     # -- decode ----------------------------------------------------------
-    def _decode(self, events: dict) -> None:
-        live = [r for r in self.slots
-                if r is not None and r.status == "running"]
+    def _decode(self, live: list[Request], events: dict) -> None:
         if not live:
             return
         B, P = self.max_slots, self.max_pages
@@ -689,7 +723,8 @@ class Scheduler:
             write_pp[b] = r.pages[r.seq_len // ps]
             writes.append((r, int(write_pp[b]), r.seq_len % ps))
         tok, ok = self.engine.decode_step(tokens, seq_lens, active,
-                                          page_tables, write_pp)
+                                          page_tables, write_pp,
+                                          lane_args(live)["rid"])
         for r in live:
             self.sessions.note_launch(r.tenant_id)
             self._record_token(r, int(tok[r.slot]), events,
@@ -707,6 +742,10 @@ class Scheduler:
         req.tokens_out.append(tok)
         req.t_last = time.monotonic()
         events["emitted"].append((req.rid, tok))
+        if len(req.tokens_out) == 1 and req.t_first > 0:
+            # scored when it happens, so a request that stalls after its
+            # first token cannot hold its TTFT out of the window's tail
+            self._h_ttft.observe((req.t_first - req.t_submit) * 1e3)
         if not ok or tok == TOKEN_POISON:
             req.status = "poisoned"
             events["poisoned"].append(req.rid)
@@ -734,14 +773,13 @@ class Scheduler:
         req.pages = []
         if self.store.exists(swap_object_id(req.rid)):
             self.store.delete(swap_object_id(req.rid))
-        # TTFT is scored at *finish* time so the preempted/clean split is
-        # final (a request can be preempted after its first token);
-        # quarantine-drained requests never score (they were cut short)
-        if req.t_first > 0 and req.status != "quarantined":
-            ttft_ms = (req.t_first - req.t_submit) * 1e3
-            self._h_ttft.observe(ttft_ms)
-            if req.swaps_out > 0:
-                self._h_pre_ttft.observe(ttft_ms)
+        # the preempted TTFT is scored at *finish* time so that it counts
+        # every request that was ever swapped out (a request can be
+        # preempted after its first token); quarantine-drained requests
+        # never score there (they were cut short)
+        if (req.t_first > 0 and req.status != "quarantined"
+                and req.swaps_out > 0):
+            self._h_pre_ttft.observe((req.t_first - req.t_submit) * 1e3)
         tid = request_tid(req.rid)
         self.tracer.end(("req", req.rid),
                         args={"tokens": len(req.tokens_out)})

@@ -32,6 +32,7 @@ from ..core import trust
 from ..core.channel import SecureChannel
 from ..core.policy import SecurityConfig
 from ..core.registers import DeviceRegisterFile, HostRegisterFile
+from ..obs import Tracer
 from ..store import SealedStore, StoreError
 
 WARM_KIND = "session_warm"
@@ -57,12 +58,15 @@ class SessionManager:
     def __init__(self, device_id: str = "tpu-0",
                  config: SecurityConfig | None = None,
                  rotate_every: int = 0,
-                 store: SealedStore | None = None):
+                 store: SealedStore | None = None,
+                 tracer: Tracer | None = None):
         """rotate_every: rotate a tenant's key after this many launches
-        (0 disables rotation).  store: optional warm-state backing tier."""
+        (0 disables rotation).  store: optional warm-state backing tier.
+        tracer: where the ``sessions.handshake`` span goes."""
         self.config = config or SecurityConfig()
         self.rotate_every = rotate_every
         self.store = store
+        self.tracer = tracer if tracer is not None else Tracer(enabled=False)
         self.device_id = device_id
         self._ca = trust.ManufacturerCA()
         self._accel = trust.TrustedAccelerator(device_id, self._ca)
@@ -90,10 +94,12 @@ class SessionManager:
                           epoch=ch.epoch, rotations=sess.rotations)
 
     # -- handshake -------------------------------------------------------
-    def _handshake(self) -> tuple:
+    def _handshake(self, tenant_id: str) -> tuple:
         """Run attestation + signed DH against the shared accelerator."""
-        host = trust.HostProgram(self._ca)
-        kbytes = host.establish(self._accel)
+        with self.tracer.span("sessions.handshake", cat="sessions",
+                              args={"tenant": tenant_id}):
+            host = trust.HostProgram(self._ca)
+            kbytes = host.establish(self._accel)
         return trust.session_key_to_words(kbytes), kbytes
 
     def register(self, tenant_id: str) -> TenantSession:
@@ -102,7 +108,7 @@ class SessionManager:
         its warm state (counters + a nonce-epoch floor)."""
         if tenant_id in self._sessions:
             return self._sessions[tenant_id]
-        key_words, key_bytes = self._handshake()
+        key_words, key_bytes = self._handshake(tenant_id)
         channel = SecureChannel(
             key_words=key_words, key_bytes=key_bytes, config=self.config,
             host_regs=HostRegisterFile(key=key_bytes),
@@ -219,7 +225,7 @@ class SessionManager:
         zero swapped-out KV objects).
         """
         sess = self.get(tenant_id)
-        key_words, key_bytes = self._handshake()
+        key_words, key_bytes = self._handshake(tenant_id)
         sess.channel.rekey(key_words, key_bytes)
         sess.launches = 0
         sess.rotations += 1
