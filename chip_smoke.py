@@ -125,7 +125,8 @@ def tamper_phase(gw, wl: Workload, prompts, clean: list) -> None:
     gw.step()                                     # admit + prefill
     victim = rids[0]
     page = gw.scheduler.requests[victim].pages[0]
-    gw.pool.k_ct = gw.pool.k_ct.at[page, 0, 0, 0, 0].add(1)
+    idx = gw.pool.elem_index(page, 0, 0, 0, 0)
+    gw.pool.k_ct = gw.pool.k_ct.at[idx].add(1)
     gw.drain()
     for i, rid in enumerate(rids):
         toks = gw.collect(rid).tolist()

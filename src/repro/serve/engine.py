@@ -193,16 +193,19 @@ class PagedEngine:
         the per-sequence verdict).
         """
         cfg = self.cfg
+        pool = self.pool
         (k_ct, v_ct, k_tags, v_tags, k_stags, v_stags, nonces, keys,
          open_flags, fill) = pool_arrays
         B, P = page_tables.shape
-        ps = self.pool.page_size
+        ps = pool.page_size
         T = P * ps
         Lc, K, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
         with jax.named_scope("kv_gather"):
+            # one stored row per page: a row gather, then the pages'
+            # [B*P, L, ps, K, hd] view
             flat_pt = page_tables.reshape(-1)
-            kp_ct = k_ct[flat_pt]
-            vp_ct = v_ct[flat_pt]
+            kp_ct = pool.from_rows(k_ct[flat_pt])
+            vp_ct = pool.from_rows(v_ct[flat_pt])
             if self.pool.sealed:
                 page_meta = (k_tags[flat_pt], v_tags[flat_pt],
                              keys[flat_pt], nonces[flat_pt])
@@ -227,12 +230,14 @@ class PagedEngine:
                 tail_idx = jnp.clip(seq_lens // ps, 0, P - 1)  # [B]
                 tail_pp = jnp.take_along_axis(page_tables, tail_idx[:, None],
                                               axis=1)[:, 0]
+                # the tail pages are gathered as rows outside the vmap: a
+                # per-lane index inside it becomes a loop over the lanes
                 ok_open = jax.vmap(
-                    lambda pp: kv_pager.verify_open_page(
-                        k_ct[pp], v_ct[pp], k_stags[pp], v_stags[pp],
-                        keys[pp], nonces[pp], fill[pp],
-                        self.pool.chunk_words)
-                )(tail_pp)
+                    lambda kc, vc, pp: kv_pager.verify_open_page(
+                        kc, vc, k_stags[pp], v_stags[pp], keys[pp],
+                        nonces[pp], fill[pp], pool.chunk_words)
+                )(pool.from_rows(k_ct[tail_pp]),
+                  pool.from_rows(v_ct[tail_pp]), tail_pp)
                 ok_closed_tail = jnp.take_along_axis(
                     ok_page, tail_idx[:, None], axis=1)[:, 0]
                 ok_tail = jnp.where(open_flags[tail_pp], ok_open,
@@ -366,8 +371,8 @@ class PagedEngine:
                 kct, vct = jax.vmap(kv_pager.bitcast_page)(kp_f, vp_f)
                 ktags = jnp.zeros((B * n_cp, self.pool.n_tags), jnp.uint32)
                 vtags = jnp.zeros((B * n_cp, self.pool.n_tags), jnp.uint32)
-            k_ct = k_ct.at[tgt].set(kct)
-            v_ct = v_ct.at[tgt].set(vct)
+            k_ct = k_ct.at[tgt].set(self.pool.to_rows(kct))
+            v_ct = v_ct.at[tgt].set(self.pool.to_rows(vct))
             k_tags = k_tags.at[tgt].set(ktags)
             v_tags = v_tags.at[tgt].set(vtags)
             if self.open_pages:
@@ -433,13 +438,14 @@ class PagedEngine:
     def _close_impl(self, pool_arrays, page):
         (k_ct, v_ct, k_tags, v_tags, k_stags, v_stags, nonces, keys,
          open_flags, fill) = pool_arrays
+        pool = self.pool
         with jax.named_scope("kv_seal"):
             kct2, vct2, ktags, vtags, ok = kv_pager.close_page(
-                k_ct[page], v_ct[page], k_stags[page], v_stags[page],
-                keys[page], nonces[page], fill[page], self.cfg.act_dtype,
-                self.pool.chunk_words)
-            k_ct = k_ct.at[page].set(kct2)
-            v_ct = v_ct.at[page].set(vct2)
+                pool.from_rows(k_ct[page]), pool.from_rows(v_ct[page]),
+                k_stags[page], v_stags[page], keys[page], nonces[page],
+                fill[page], self.cfg.act_dtype, pool.chunk_words)
+            k_ct = k_ct.at[page].set(pool.to_rows(kct2))
+            v_ct = v_ct.at[page].set(pool.to_rows(vct2))
             k_tags = k_tags.at[page].set(ktags)
             v_tags = v_tags.at[page].set(vtags)
             k_stags = k_stags.at[page].set(0)
@@ -479,12 +485,13 @@ class PagedEngine:
     def _reopen_impl(self, pool_arrays, page, fill_n):
         (k_ct, v_ct, k_tags, v_tags, k_stags, v_stags, nonces, keys,
          open_flags, fill) = pool_arrays
+        pool = self.pool
         kct2, vct2, kst, vst, ok = kv_pager.reopen_page(
-            k_ct[page], v_ct[page], k_tags[page], v_tags[page],
-            keys[page], nonces[page], self.cfg.act_dtype,
-            self.pool.chunk_words)
-        k_ct = k_ct.at[page].set(kct2)
-        v_ct = v_ct.at[page].set(vct2)
+            pool.from_rows(k_ct[page]), pool.from_rows(v_ct[page]),
+            k_tags[page], v_tags[page], keys[page], nonces[page],
+            self.cfg.act_dtype, pool.chunk_words)
+        k_ct = k_ct.at[page].set(pool.to_rows(kct2))
+        v_ct = v_ct.at[page].set(pool.to_rows(vct2))
         k_tags = k_tags.at[page].set(0)
         v_tags = v_tags.at[page].set(0)
         k_stags = k_stags.at[page].set(kst)
@@ -519,17 +526,18 @@ class PagedEngine:
     def _renonce_impl(self, pool_arrays, page, fresh):
         (k_ct, v_ct, k_tags, v_tags, k_stags, v_stags, nonces, keys,
          open_flags, fill) = pool_arrays
+        pool = self.pool
         k, v, ok = kv_pager.unseal_page(
-            k_ct[page], v_ct[page], k_tags[page], v_tags[page],
-            keys[page], nonces[page], self.cfg.act_dtype,
-            self.pool.chunk_words)
+            pool.from_rows(k_ct[page]), pool.from_rows(v_ct[page]),
+            k_tags[page], v_tags[page], keys[page], nonces[page],
+            self.cfg.act_dtype, pool.chunk_words)
         kct2, vct2, ktags2, vtags2 = kv_pager.seal_page(
-            k, v, keys[page], fresh, self.pool.chunk_words)
+            k, v, keys[page], fresh, pool.chunk_words)
         # fail closed: a page that did not verify under its old nonce must
         # not come back verifiable under the fresh one
         poison = jnp.where(ok, jnp.uint32(0), jnp.uint32(0xA5A5A5A5))
-        k_ct = k_ct.at[page].set(kct2)
-        v_ct = v_ct.at[page].set(vct2)
+        k_ct = k_ct.at[page].set(pool.to_rows(kct2))
+        v_ct = v_ct.at[page].set(pool.to_rows(vct2))
         k_tags = k_tags.at[page].set(ktags2 ^ poison)
         v_tags = v_tags.at[page].set(vtags2 ^ poison)
         nonces = nonces.at[page].set(jnp.asarray(fresh, jnp.uint32))
@@ -583,12 +591,13 @@ class PagedEngine:
     def _cow_impl(self, pool_arrays, src, dst, src_key, fill_n):
         (k_ct, v_ct, k_tags, v_tags, k_stags, v_stags, nonces, keys,
          open_flags, fill) = pool_arrays
+        pool = self.pool
         kct2, vct2, kst, vst, ok = kv_pager.cow_page(
-            k_ct[src], v_ct[src], k_tags[src], v_tags[src],
-            src_key, nonces[src], keys[dst], nonces[dst],
-            self.cfg.act_dtype, self.pool.chunk_words)
-        k_ct = k_ct.at[dst].set(kct2)
-        v_ct = v_ct.at[dst].set(vct2)
+            pool.from_rows(k_ct[src]), pool.from_rows(v_ct[src]),
+            k_tags[src], v_tags[src], src_key, nonces[src], keys[dst],
+            nonces[dst], self.cfg.act_dtype, pool.chunk_words)
+        k_ct = k_ct.at[dst].set(pool.to_rows(kct2))
+        v_ct = v_ct.at[dst].set(pool.to_rows(vct2))
         k_tags = k_tags.at[dst].set(0)
         v_tags = v_tags.at[dst].set(0)
         k_stags = k_stags.at[dst].set(kst)
@@ -715,8 +724,8 @@ class PagedEngine:
                     vct_s = jax.lax.bitcast_convert_type(v_slot, udt)
                 # idle lanes hit (SCRATCH_PAGE, slot 0); live lanes hold
                 # distinct pages, so no meaningful scatter collisions
-                k_ct = k_ct.at[write_pp, :, slot].set(kct_s)
-                v_ct = v_ct.at[write_pp, :, slot].set(vct_s)
+                k_ct = self.pool.write_slots(k_ct, write_pp, slot, kct_s)
+                v_ct = self.pool.write_slots(v_ct, write_pp, slot, vct_s)
                 fill = fill.at[write_pp].set(slot + 1)
             else:
                 # -- legacy write-back: reseal the whole tail page ----------
@@ -745,8 +754,8 @@ class PagedEngine:
                     vtags_n = jnp.zeros((B, self.pool.n_tags), jnp.uint32)
                 # idle lanes target SCRATCH_PAGE; live lanes hold distinct
                 # pages, so the scatter has no meaningful index collisions.
-                k_ct = k_ct.at[write_pp].set(kct_n)
-                v_ct = v_ct.at[write_pp].set(vct_n)
+                k_ct = k_ct.at[write_pp].set(self.pool.to_rows(kct_n))
+                v_ct = v_ct.at[write_pp].set(self.pool.to_rows(vct_n))
                 k_tags = k_tags.at[write_pp].set(ktags_n)
                 v_tags = v_tags.at[write_pp].set(vtags_n)
                 nonces = nonces.at[write_pp].set(nonce_w)

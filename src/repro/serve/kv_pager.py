@@ -10,6 +10,18 @@ slots across all layers:
 and variable-length sequences map onto the shared pool through per-sequence
 page tables (vLLM-style), gathered in-graph at decode time.
 
+The pool stores each page's ciphertext page-major, as one contiguous row:
+``k_ct``/``v_ct`` are ``[n_pages, rows, 128]``, a page's elements in their
+``[n_layers, page_size, n_kv_heads, hd]`` row-major order filling lane-dense
+rows of 128 (``PagedKVPool.row_shape``; the last row is zero-padded when
+the count is not a multiple of 128).  The page index is the most-major
+dimension, so a page-table gather is one row gather that moves each page
+as one block.  A 5-D ``[n_pages, L, ps, K, hd]`` pool with hd = 64 takes a
+page-minor layout on the TPU, where the same gather becomes a loop that
+copies one page per step.  Sealing and verification work on a page's
+``[L, ps, K, hd]`` view (``PagedKVPool.from_rows``/``to_rows``), so the
+sealed bytes do not depend on how the pool stores them.
+
 Security model (paper Rules 1/2, per page):
   * confidentiality — each page is CTR-encrypted under the *owning tenant's*
     session key, via k/v lane subkeys, with a per-page nonce; every re-seal
@@ -72,6 +84,8 @@ KV_SLICE_DOMAIN = 0x534C43  # "SLC" — per-slot slice-tag key lane
 
 SCRATCH_PAGE = 0  # physical page 0 is never allocated: pad entries in page
                   # tables and write-back lanes of idle slots target it.
+
+LANES = 128  # minor dimension of a stored page: one TPU lane row
 
 
 class PoolExhausted(RuntimeError):
@@ -343,8 +357,7 @@ class PagedKVPool:
     def __post_init__(self):
         if self.tracer is None:
             self.tracer = Tracer(enabled=False)
-        shape = (self.n_pages, self.n_layers, self.page_size,
-                 self.n_kv_heads, self.hd)
+        shape = (self.n_pages,) + self.row_shape
         udt = cipher.uint_dtype_for(self.dtype)
         pw = page_words(self.n_layers, self.page_size, self.n_kv_heads,
                         self.hd, self.dtype)
@@ -450,6 +463,62 @@ class PagedKVPool:
     @property
     def page_bytes(self) -> int:
         return self.slot_bytes * self.page_size
+
+    # -- stored form -----------------------------------------------------
+    @property
+    def page_shape(self) -> tuple[int, int, int, int]:
+        """One page's ``[n_layers, page_size, n_kv_heads, hd]`` view."""
+        return (self.n_layers, self.page_size, self.n_kv_heads, self.hd)
+
+    @property
+    def row_shape(self) -> tuple[int, int]:
+        """``(rows, LANES)``: one page as ``k_ct``/``v_ct`` store it, its
+        ``page_shape`` elements in row-major order, the last row
+        zero-padded."""
+        n = int(np.prod(self.page_shape))
+        return (-(-n // LANES), LANES)
+
+    def to_rows(self, pages: jax.Array) -> jax.Array:
+        """``[..., L, ps, K, hd]`` pages -> ``[..., rows, LANES]`` stored
+        form; the element order is kept, so the sealed bytes are too."""
+        lead = pages.shape[:-4]
+        flat = pages.reshape(lead + (-1,))
+        pad = self.row_shape[0] * LANES - flat.shape[-1]
+        if pad:
+            flat = jnp.pad(flat, [(0, 0)] * len(lead) + [(0, pad)])
+        return flat.reshape(lead + self.row_shape)
+
+    def from_rows(self, rows: jax.Array) -> jax.Array:
+        """``[..., rows, LANES]`` stored pages -> ``[..., L, ps, K, hd]``.
+        Apply it to gathered pages only: the whole pool in this view takes
+        the page-minor layout that the stored form avoids."""
+        lead = rows.shape[:-2]
+        n = int(np.prod(self.page_shape))
+        return rows.reshape(lead + (-1,))[..., :n].reshape(
+            lead + self.page_shape)
+
+    def write_slots(self, ct: jax.Array, pages: jax.Array, slots: jax.Array,
+                    slot_ct: jax.Array) -> jax.Array:
+        """``ct`` (stored ``k_ct`` or ``v_ct``) with ``slot_ct[b]``
+        ([L, K, hd]) written at slot ``slots[b]`` of page ``pages[b]``.
+
+        Each page is read as its row, the slot set in its [L, ps, K, hd]
+        view and the row written back whole, so the write is one row
+        gather and one row scatter.  Where a page repeats (idle lanes on
+        SCRATCH_PAGE) one of its writes wins.
+        """
+        def put(rows, sct, s):
+            page = jax.lax.dynamic_update_slice(self.from_rows(rows),
+                                                sct[:, None], (0, s, 0, 0))
+            return self.to_rows(page)
+        return ct.at[pages].set(jax.vmap(put)(ct[pages], slot_ct, slots))
+
+    def elem_index(self, page: int, layer: int, slot: int, head: int,
+                   dim: int) -> tuple[int, int, int]:
+        """Index into ``k_ct``/``v_ct`` of one element of a page."""
+        i = ((layer * self.page_size + slot) * self.n_kv_heads + head) \
+            * self.hd + dim
+        return (page, i // LANES, i % LANES)
 
     # -- allocator -------------------------------------------------------
     @property
@@ -732,7 +801,8 @@ class PagedKVPool:
 
     # -- device state ----------------------------------------------------
     def write_pages(self, pages: list[int], kct, vct, ktags, vtags) -> None:
-        """Install freshly sealed CLOSED page contents (swap-in, tests)."""
+        """Install sealed CLOSED page contents in stored form (swap-in of
+        what ``export_pages`` gave)."""
         idx = jnp.asarray(pages, jnp.int32)
         self.k_ct = self.k_ct.at[idx].set(kct)
         self.v_ct = self.v_ct.at[idx].set(vct)

@@ -684,7 +684,7 @@ class Scheduler:
             return None
         n = len(req.swap_nonces)
         p = self.pool
-        page_shape = (n, p.n_layers, p.page_size, p.n_kv_heads, p.hd)
+        page_shape = (n,) + p.row_shape
         want = {"k_ct": (page_shape, p.k_ct.dtype),
                 "v_ct": (page_shape, p.v_ct.dtype),
                 "k_tags": ((n, p.n_tags), p.k_tags.dtype),

@@ -220,7 +220,8 @@ def test_tamper_storm_quarantines_only_the_offending_tenant(setup, reference):
     gw.step()                                   # admit + prefill
     for rid in mallory:
         page = gw.scheduler.requests[rid].pages[0]
-        gw.pool.k_ct = gw.pool.k_ct.at[page, 0, 0, 0, 0].add(1)
+        idx = gw.pool.elem_index(page, 0, 0, 0, 0)
+        gw.pool.k_ct = gw.pool.k_ct.at[idx].add(1)
     gw.drain()
 
     # the storm fired, attributed to mallory, and the handler quarantined it

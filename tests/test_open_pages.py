@@ -228,7 +228,8 @@ def test_slice_tamper_in_open_page_poisons_only_owner(setup, gw_open,
     fill = int(gw_open.pool.fill[tail])
     assert fill >= 1
     # flip one ciphertext bit inside a *written* slot of the open page
-    gw_open.pool.k_ct = gw_open.pool.k_ct.at[tail, 0, fill - 1, 0, 0].add(1)
+    idx = gw_open.pool.elem_index(tail, 0, fill - 1, 0, 0)
+    gw_open.pool.k_ct = gw_open.pool.k_ct.at[idx].add(1)
     gw_open.drain()
     assert gw_open.status(rid_a) == "poisoned"
     assert gw_open.scheduler.requests[rid_a].tokens_out[-1] == TOKEN_POISON

@@ -247,7 +247,8 @@ def test_shared_tamper_poisons_only_mapped_requests(setup, gw, gw0):
     assert gw.scheduler.requests[rid_hit].shared_mapped
     page = entry.pages[0]
     assert gw.pool.ref_count(page) == 1
-    gw.pool.k_ct = gw.pool.k_ct.at[page, 0, 0, 0, 0].add(1)
+    idx = gw.pool.elem_index(page, 0, 0, 0, 0)
+    gw.pool.k_ct = gw.pool.k_ct.at[idx].add(1)
     gw.drain()
     assert gw.status(rid_hit) == "poisoned"
     assert gw.scheduler.requests[rid_hit].tokens_out[-1] == TOKEN_POISON
@@ -305,7 +306,8 @@ def test_cow_broken_page_immune_to_later_tamper(setup, gw, gw0):
     assert gw.pool.owner_of(cow_page) == "cora"
     assert gw.pool.ref_count(entry.tail_page) == 0   # tail mapped only for COW
     # now corrupt the shared original tail
-    gw.pool.k_ct = gw.pool.k_ct.at[entry.tail_page, 0, 0, 0, 0].add(1)
+    idx = gw.pool.elem_index(entry.tail_page, 0, 0, 0, 0)
+    gw.pool.k_ct = gw.pool.k_ct.at[idx].add(1)
     gw.drain()
     assert gw.status(rid_a) == "done"
     np.testing.assert_array_equal(gw.collect(rid_a), ref)   # unaffected

@@ -155,7 +155,8 @@ def test_tampered_page_poisons_only_owner(setup, gateway, reference):
     gateway.step()                            # both admitted + one decode
     req_a = gateway.scheduler.requests[rid_a]
     page = req_a.pages[0]                     # a page holding alice's prompt
-    gateway.pool.k_ct = gateway.pool.k_ct.at[page, 0, 0, 0, 0].add(1)
+    idx = gateway.pool.elem_index(page, 0, 0, 0, 0)
+    gateway.pool.k_ct = gateway.pool.k_ct.at[idx].add(1)
     gateway.drain()
     assert gateway.status(rid_a) == "poisoned"
     assert gateway.scheduler.requests[rid_a].tokens_out[-1] == TOKEN_POISON
@@ -504,7 +505,8 @@ def test_tampered_request_emits_tamper_audit_record(setup, gateway,
     rid_b = gateway.submit("bob", prompts["bob"], max_new=N_NEW)
     gateway.step()
     page = gateway.scheduler.requests[rid_a].pages[0]
-    gateway.pool.k_ct = gateway.pool.k_ct.at[page, 0, 0, 0, 0].add(1)
+    idx = gateway.pool.elem_index(page, 0, 0, 0, 0)
+    gateway.pool.k_ct = gateway.pool.k_ct.at[idx].add(1)
     gateway.drain()
     assert gateway.status(rid_a) == "poisoned"
     assert gateway.status(rid_b) == "done"

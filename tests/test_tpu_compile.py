@@ -10,6 +10,7 @@ imported: only one process may load the TPU library at a time, and the
 test workers import every test file.  All such tests stay in this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -80,3 +81,75 @@ def test_sealed_attention_compiles_for_decode(one_chip):
              ((B, K, G, hd), bf16), ((B, T, K, hd), u16),
              ((B, T, K, hd), u16), ((B, T, K, 1), u32), ((B, T, K, 1), u32),
              ((2,), u32), ((2,), u32), ((hd // 2,), u32), ((), jnp.int32))
+
+
+# -- the paged engine's pool layout -----------------------------------------
+# The chat cell's gateway: 4 slots, pages of 16 tokens, 44 pages a sequence,
+# 197 pages, prefill chunks of 256 tokens, granite-3-2b's 40 layers.  The
+# page-table gather lowers to a loop over pages only at this depth.
+SLOTS, PAGE, PAGES_PER_SEQ, N_PAGES, CHUNK = 4, 16, 44, 197, 256
+
+
+@pytest.fixture(scope="module")
+def engine_hlo(one_chip):
+    """Compiled text of the trusted decode and chunk-prefill programs."""
+    from repro.core import sealed
+    from repro.core.policy import SecurityConfig
+    from repro.models import registry
+    from repro.serve.engine import PagedEngine
+    from repro.serve.kv_pager import PagedKVPool
+    from repro.serve.sessions import SessionManager
+
+    sec = SecurityConfig()
+    provider = SessionManager("tpu-0", config=sec).register("_provider") \
+        .channel
+    pool = PagedKVPool(n_pages=2, page_size=PAGE, n_layers=CFG.n_layers,
+                       n_kv_heads=CFG.n_kv_heads, hd=CFG.hd,
+                       dtype=CFG.act_dtype)
+    engine = PagedEngine(cfg=CFG, params=None, channel=provider, pool=pool,
+                         max_slots=SLOTS, max_pages=PAGES_PER_SEQ,
+                         prefill_chunk=CHUNK)
+    params = jax.eval_shape(lambda: sealed.seal_tree(
+        registry.init_params(jax.random.PRNGKey(0), CFG), provider.jkey,
+        sec.weights))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), params)
+    pool_arrays = tuple(sds((N_PAGES,) + a.shape[1:], a.dtype)
+                        for a in pool.arrays())
+    B, P, i32 = SLOTS, PAGES_PER_SEQ, jnp.int32
+    decode = engine._decode.lower(
+        params, sds((B,), i32), sds((B,), i32), sds((B,), bool),
+        sds((B, P), i32), sds((B,), i32), pool_arrays).compile()
+    prefill = engine._chunk_prefill.lower(
+        params, sds((B, CHUNK), i32), sds((B,), i32), sds((B,), i32),
+        sds((B,), bool), sds((B, P), i32), pool_arrays).compile()
+    stored = "u16[%d,%s]" % (N_PAGES, ",".join(map(str, pool.row_shape)))
+    return {"decode": decode.as_text(), "chunk_prefill": prefill.as_text(),
+            "stored": stored}
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_prefill"])
+def test_no_loop_carries_the_kv_pool(engine_hlo, program):
+    text = engine_hlo[program]
+    loops = [ln for ln in text.splitlines() if " while(" in ln]
+    assert loops, "expected the layer loop at least"
+    carrying = [ln for ln in loops if f"u16[{N_PAGES}," in ln]
+    assert not carrying, carrying[0][:300]
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk_prefill"])
+def test_kv_pool_is_stored_page_major(engine_hlo, program):
+    text = engine_hlo[program]
+    entry = text[text.index("\nENTRY"):]
+    stored = engine_hlo["stored"]
+    # the k_ct and v_ct parameters, with the layouts the compiler gave them
+    params = re.findall(
+        r"= (u16\[%d,[0-9,]+\])\{([0-9,]+)[:}][^\n]*parameter\(" % N_PAGES,
+        entry)
+    assert len(params) == 2, params
+    for shape, layout in params:
+        assert shape == stored
+        # minor-to-major: the page dimension (0) is the most major
+        assert layout.split(",")[-1] == "0", (shape, layout)
