@@ -2,12 +2,12 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the requests the window finished, drawn from the seed and holding the one
-with the most served tokens, goes through the reference: once over each
-prompt with its served tokens.  At each served position the reference's
-logits give the gap by which the served token lies below the reference's
-best token.  The widest gap over the sample is compared with the cell's
-limit.  Served tokens are greedy, so a sound run's gaps come from rounding
-alone.
+with the most served tokens and one that decoded in each decode lane, goes
+through the reference: once over each prompt with its served tokens.  At
+each served position the reference's logits give the gap by which the
+served token lies below the reference's best token.  The widest gap over
+the sample is compared with the cell's limit.  Served tokens are greedy,
+so a sound run's gaps come from rounding alone.
 
 The control (``quant``) puts the reference, computed in a lower precision,
 in the program's place: at each position of the same prompts and tokens it
@@ -28,8 +28,11 @@ SAMPLE_MAX = 8          # requests a sample holds at most
 
 
 def sample(records, seed: int) -> list:
-    """Finished requests to check: the longest, then others in an order of
-    the seed's until SAMPLE_TOKENS served tokens or SAMPLE_MAX requests."""
+    """Finished requests to check: the longest; then, in an order of the
+    seed's, one that decoded in each decode lane (slot) no request picked
+    so far decoded in, so that a fault confined to one lane is caught;
+    then others until SAMPLE_TOKENS served tokens or SAMPLE_MAX
+    requests."""
     done = sorted((r for r in records if r.status == "done"),
                   key=lambda r: r.req.index)
     if not done:
@@ -39,8 +42,15 @@ def sample(records, seed: int) -> list:
     rng = np.random.default_rng(np.random.SeedSequence(
         [int(seed) & 0xFFFFFFFF, int(seed) >> 32, 99]))
     order = [rest[i] for i in rng.permutation(len(rest))]
-    picked, n_tok = [longest], len(longest.tokens)
+    picked, covered, later = [longest], set(longest.lanes), []
     for r in order:
+        if r.lanes - covered:
+            picked.append(r)
+            covered |= r.lanes
+        else:
+            later.append(r)
+    n_tok = sum(len(r.tokens) for r in picked)
+    for r in later:
         if n_tok >= SAMPLE_TOKENS or len(picked) >= SAMPLE_MAX:
             break
         picked.append(r)

@@ -29,6 +29,7 @@ class Record:
     tokens: list = dataclasses.field(default_factory=list)
     t_done: float | None = None
     status: str = "queued"
+    lanes: set = dataclasses.field(default_factory=set)  # decode slots held
 
 
 @dataclasses.dataclass
@@ -136,7 +137,7 @@ class Load:
 
     @staticmethod
     def _state(r) -> tuple:
-        return r.prefill_pos, len(r.tokens_out)
+        return r.prefill_pos, len(r.tokens_out), r.slot
 
     def _account(self, events: dict, before: dict, t0: float,
                  t1: float) -> None:
@@ -147,7 +148,7 @@ class Load:
                 rec.emits.append(t1)
                 rec.tokens.append(int(tok))
         prefill, decode = [], []
-        for rid, (pos0, n0) in before.items():
+        for rid, (pos0, n0, slot0) in before.items():
             r = sched.requests[rid]
             n1 = len(r.tokens_out)
             if r.prefill_pos > pos0:
@@ -159,6 +160,9 @@ class Load:
                 # prompt + n - 1 positions and writes one: it attends over
                 # prompt + n
                 decode.append(r.prompt_len + n)
+            if n1 > n0:
+                # a request that finished in this step has left its slot
+                self.by_rid[rid].lanes.add(r.slot if r.slot >= 0 else slot0)
         self.steps.append(Step(t0=t0, t1=t1, prefill=prefill, decode=decode))
         for rid in list(events["finished"]) + list(events["poisoned"]):
             rec = self.by_rid.get(rid)

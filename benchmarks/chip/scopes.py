@@ -16,8 +16,16 @@ small reader of the protobuf wire format (``XSpace`` > ``XPlane`` >
                  another (a ``while`` and its body) counts once, with the
                  outermost; one whose own metadata names no scope takes the
                  scope of the first operation nested in it that does.
+    nested scope any other scope, read when a reader asks for it by name
+                 wherever it sits on an operation's path
+                 (``jit(_decode_impl)/model/while/body/experts/...``): the
+                 time of the operations whose own metadata carries it and
+                 that lie inside no other operation that does, each counted
+                 once.  It is a part of the engine's scope that holds it,
+                 whose reading it leaves as it is.
     host spans   the program's ``Tracer.span`` annotations, which
-                 ``jax.profiler`` records beside the device's operations.
+                 ``jax.profiler`` records beside the device's operations:
+                 those named in SPANS and those a reader asks for.
 
 Device times move onto the host clock by the shift ``trace.py`` computes.
 A program without scopes or spans (one older than them) gives None, never
@@ -51,13 +59,24 @@ class Scoped:
     spans: list                 # [(name, start_ns, end_ns)] in the window,
                                 # by start
     window: tuple               # (start_ns, end_ns) of bench.traced
+    devices: list = dataclasses.field(default_factory=list, repr=False)
+                                # per device: decode runs [(start, end)]
+                                # and ops [(start, end, tf_op)], outer first
 
     def per_call_ms(self, scope: str):
-        """Device ms of ``scope`` per decode call; None when the program
-        has no scopes or no decode ran."""
+        """Device ms of ``scope`` per decode call: one of SCOPES, or a scope
+        nested in them.  None when the program has no scopes, no decode ran
+        or no operation carries a nested ``scope``."""
         if not self.scope_ns or not self.decode_calls:
             return None
-        return self.scope_ns.get(scope, 0.0) / self.decode_calls * 1e-6
+        if scope in SCOPES:
+            ns = self.scope_ns.get(scope, 0.0)
+        else:
+            ns = sum(_nested_ns(runs, ops, scope)
+                     for runs, ops in self.devices)
+            if not ns:
+                return None
+        return ns / self.decode_calls * 1e-6
 
 
 # -- protobuf wire format ---------------------------------------------------
@@ -125,10 +144,10 @@ def _map_entry(buf) -> tuple:
     return key, value
 
 
-def _plane(buf) -> tuple:
+def _plane(buf, spans=SPANS) -> tuple:
     """XPlane -> (name, {line name: [(metadata id, start ns, end ns)]},
-    {event metadata id: (name, tf_op or None)}), keeping the lines and
-    events that ``_wanted`` names."""
+    {event metadata id: (name, tf_op or None)}), keeping the device's
+    lines and, on the host, the events ``_wanted_span`` keeps."""
     name, raw_lines, raw_meta, stat_names = "", [], [], {}
     for f, v in _fields(buf):
         if f == 2:
@@ -162,7 +181,7 @@ def _plane(buf) -> tuple:
         meta[mid] = (ename, op)
     device = bool(trace_lib.DEVICE.match(name))
     keep = {mid for mid, (ename, _) in meta.items()
-            if device or _wanted_span(ename)}
+            if device or _wanted_span(ename, spans)}
     lines = {}
     for raw in raw_lines:
         lname, ts_ns, events = "", 0, []
@@ -194,8 +213,8 @@ def _plane(buf) -> tuple:
     return name, lines, meta
 
 
-def _wanted_span(name: str) -> bool:
-    return (name in SPANS or name == trace_lib.WINDOW
+def _wanted_span(name: str, spans: tuple) -> bool:
+    return (name in spans or name == trace_lib.WINDOW
             or name.startswith("PjitFunction("))
 
 
@@ -211,10 +230,15 @@ def scope_of(tf_op) -> str | None:
 
 
 # -- reduction ----------------------------------------------------------------
+def carries(tf_op, scope: str) -> bool:
+    """Whether ``scope`` is an element of the operation's path."""
+    return bool(tf_op) and scope in tf_op.split(":", 1)[0].split("/")
+
+
 @functools.lru_cache(maxsize=4)
-def _parse(path: str, _mtime_ns: int) -> Scoped:
+def _parse(path: str, _mtime_ns: int, spans: tuple) -> Scoped:
     data = memoryview(pathlib.Path(path).read_bytes())
-    planes = [_plane(v) for f, v in _fields(data) if f == 1]
+    planes = [_plane(v, spans) for f, v in _fields(data) if f == 1]
     host, pjit, devices = [], [], []
     for name, lines, meta in planes:
         if trace_lib.DEVICE.match(name):
@@ -223,7 +247,7 @@ def _parse(path: str, _mtime_ns: int) -> Scoped:
         for events in lines.values():
             for mid, a, b in events:
                 ename = meta.get(mid, ("", None))[0]
-                if ename in SPANS or ename == trace_lib.WINDOW:
+                if ename in spans or ename == trace_lib.WINDOW:
                     host.append((ename, a, b))
                 elif ename.startswith("PjitFunction("):
                     pjit.append(("jit_" + ename[13:-1], a, b))
@@ -233,7 +257,7 @@ def _parse(path: str, _mtime_ns: int) -> Scoped:
                          "device plane")
     t0, t1 = min(a for a, _ in marks), max(b for _, b in marks)
     dispatches = trace_lib._outermost(pjit)
-    calls, decode_ns, scope_ns = 0, 0.0, {}
+    calls, decode_ns, scope_ns, read_ops = 0, 0.0, {}, []
     for lines, meta in devices:
         mods = [(trace_lib._program_name(meta.get(mid, ("", None))[0]), a, b)
                 for mid, a, b in lines.get("XLA Modules", [])]
@@ -247,11 +271,12 @@ def _parse(path: str, _mtime_ns: int) -> Scoped:
                       for mid, a, b in lines.get("XLA Ops", [])),
                      key=lambda op: (op[0], -op[1]))  # outer op first
         _charge(runs, ops, scope_ns)
-    spans = sorted(((n, a, b) for n, a, b in host
+        read_ops.append((runs, ops))
+    found = sorted(((n, a, b) for n, a, b in host
                     if n != trace_lib.WINDOW and b > t0 and a < t1),
                    key=lambda span: span[1])
     return Scoped(decode_calls=calls, decode_ns=decode_ns, scope_ns=scope_ns,
-                  spans=spans, window=(t0, t1))
+                  spans=found, window=(t0, t1), devices=read_ops)
 
 
 def _charge(runs: list, ops: list, scope_ns: dict) -> None:
@@ -272,17 +297,36 @@ def _charge(runs: list, ops: list, scope_ns: dict) -> None:
         outer = [a, b, scope_of(tf_op)]
 
 
-def read(path) -> Scoped:
-    """The reduction of one ``.xplane.pb``."""
+def _nested_ns(runs: list, ops: list, scope: str) -> float:
+    """The time of each operation that carries ``scope``, starts inside one
+    of ``runs`` and lies inside no other operation that carries it.
+    ``ops`` come outer first."""
+    starts = [a for a, _ in runs]
+    total, end = 0.0, float("-inf")
+    for a, b, tf_op in ops:
+        if a < end or not carries(tf_op, scope):
+            continue
+        end = b
+        i = bisect.bisect_right(starts, a) - 1
+        if i >= 0 and a < runs[i][1]:
+            total += b - a
+    return total
+
+
+def read(path, spans=()) -> Scoped:
+    """The reduction of one ``.xplane.pb``, keeping the host ``spans`` asked
+    for beside SPANS."""
     path = pathlib.Path(path)
-    return _parse(str(path), path.stat().st_mtime_ns)
+    return _parse(str(path), path.stat().st_mtime_ns,
+                  SPANS + tuple(s for s in dict.fromkeys(spans)
+                                if s not in SPANS))
 
 
-def of_run(run) -> Scoped | None:
-    """The reduction of the trace a traced run wrote; None for a run with
-    no trace."""
+def of_run(run, spans=()) -> Scoped | None:
+    """The reduction of the trace a traced run wrote, keeping the host
+    ``spans`` asked for beside SPANS; None for a run with no trace."""
     if getattr(run, "trace", None) is None:
         return None
     files = sorted(TRACE_DIR.glob("plugins/profile/*/*.xplane.pb"),
                    key=lambda p: p.stat().st_mtime_ns)
-    return read(files[-1]) if files else None
+    return read(files[-1], spans) if files else None

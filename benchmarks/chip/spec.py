@@ -7,10 +7,26 @@
                             and the limits its outputs are checked against
     metrics/<metric>.py     a metric's reader: ``read(run)`` -> a number, or
                             None where the run holds nothing to read
+    families/<family>.py    a model family, found by the configuration's
+                            ``model.family`` (the program's own name):
+        leaves(cfg)             {path: (shape, rule) or (shape, rule, dtype)}
+                                of every weight leaf, path joined by '/';
+                                weights.py says what a rule is
+        param_count(m), weight_bytes(m), kv_bytes_per_token(m),
+        decode_flops(m, contexts), prefill_flops(m, lanes),
+        decode_min_bytes(m, contexts)
+                                counts of the engine's calls from the
+                                configuration's ``model`` block (costs.py)
+    references/<name>.py    the plain reference the configuration names
+                            under ``reference`` (check.py)
+
+A reader may also ask scopes.py for a named scope nested in the engine's
+own (an ``experts`` scope inside the layer loop) and for host spans of the
+program beyond its SPANS, by name.
 
 A cell's metrics are listed in BENCHMARK.json at the checkout's root.  A new
-cell, mix, configuration or metric is a new file and a new entry there; no
-code here names one.
+cell, mix, configuration, family or metric is a new file, and a cell,
+configuration or metric a new entry there too; no code here names one.
 """
 from __future__ import annotations
 
@@ -120,3 +136,16 @@ def peaks(device_kind: str, data: pathlib.Path = HERE) -> dict:
         raise KeyError(f"no peaks for device kind {device_kind!r} in "
                        f"{data / 'peaks.json'}")
     return table["devices"][device_kind]
+
+
+def family(name: str):
+    """The module families/<name>.py: a family with no module is an error,
+    never a fall-back to another family's tree or counts."""
+    module = f"{__package__}.families.{name}"
+    try:
+        return importlib.import_module(module)
+    except ModuleNotFoundError as e:
+        if e.name != module:
+            raise
+        raise KeyError(f"no family module {HERE / 'families' / name}.py "
+                       f"for model family {name!r}") from None

@@ -1,11 +1,13 @@
 """The one traffic generator: requests drawn from a mix file and a seed.
 
-Every seed gets the same work in another order.  Lengths, gaps between
-arrivals and tenants are drawn in blocks of ``block`` requests; each block
-holds the distribution's quantiles at (i + 0.5) / block, shuffled by the
-seed.  So any stretch of a few blocks carries nearly the same tokens and the
-same arrivals whatever the seed, and runs of different seeds differ by the
-order of the work and by the token ids, not by how much work there is.
+Every seed gets the same work in the same order.  Lengths and gaps between
+arrivals are drawn in blocks of ``block`` requests; each block holds the
+distribution's quantiles at (i + 0.5) / block, in one fixed order
+(``ORDER_SEED``'s).  The seed draws the token ids and which tenant sends each
+request.  The order is not the seed's because a window of a cell above its
+capacity serves a stretch of the stream that the order decides: with an
+order of the seed's, the tokens a 51 s window completed on one TPU v5e changed
+by about 1 % from seed to seed, as much as the bound that the cell holds.
 
 A mix names its loop:
 
@@ -29,6 +31,7 @@ import numpy as np
 MIN_STEP_S = 0.02       # no gateway step of a served model is shorter
 STREAMS = {"prompt_len": 1, "output_len": 2, "gaps": 3, "tenants": 4,
            "tokens": 5}
+ORDER_SEED = 0          # orders the lengths and gaps of every seed's stream
 
 
 @dataclasses.dataclass
@@ -47,7 +50,7 @@ def _rng(seed: int, stream: str) -> np.random.Generator:
 
 def _quantiles(n: int, block: int, rng: np.random.Generator) -> np.ndarray:
     """n stratified uniforms: each block of ``block`` holds the quantiles
-    (i + 0.5) / block once, in an order of the seed's."""
+    (i + 0.5) / block once, in the order ``rng`` draws."""
     base = (np.arange(block) + 0.5) / block
     blocks = [rng.permutation(base) for _ in range(-(-n // block))]
     return np.concatenate(blocks)[:n]
@@ -70,9 +73,9 @@ def generate(mix: dict, seed: int, vocab: int, n: int,
     """The first n requests of the stream this mix and seed give."""
     block = int(mix["block"])
     plen = length_ppf(mix["prompt"], _quantiles(
-        n, block, _rng(seed, "prompt_len")))
+        n, block, _rng(ORDER_SEED, "prompt_len")))
     olen = length_ppf(mix["output"], _quantiles(
-        n, block, _rng(seed, "output_len")))
+        n, block, _rng(ORDER_SEED, "output_len")))
     n_ten = int(mix["tenants"])
     t_rng = _rng(seed, "tenants")
     tenants = np.concatenate([t_rng.permutation(n_ten)
@@ -80,7 +83,7 @@ def generate(mix: dict, seed: int, vocab: int, n: int,
     if mix["loop"] == "open":
         if not rate_per_s or rate_per_s <= 0:
             raise ValueError("an open loop needs a rate_per_s above 0")
-        u = _quantiles(n, block, _rng(seed, "gaps"))
+        u = _quantiles(n, block, _rng(ORDER_SEED, "gaps"))
         t_due = np.cumsum(-np.log1p(-u) / rate_per_s)
     elif mix["loop"] == "closed":
         t_due = [None] * n

@@ -100,3 +100,20 @@ def test_the_fp8_control_fails_where_bf16_passes(tmp_path):
     ctl = check.gaps(cell.config, served.cfg, served.seed, served.picked,
                      **check.shapes(cell.mix), quant="fp8")
     assert max(float(g.max()) for g in ctl) > limit
+
+
+@pytest.mark.parametrize("seed", [0, 2**31 + 5, 2**33 + 17])
+def test_the_sample_holds_a_request_of_every_lane(seed):
+    """A fault confined to one decode lane alters only the requests that
+    decoded there: the sample takes one of them, however few there are,
+    beside the longest request and the seed's draw."""
+    import types
+    recs = [types.SimpleNamespace(
+        req=types.SimpleNamespace(index=i), status="done",
+        tokens=[0] * (100 + i), lanes={i % 3}) for i in range(30)]
+    recs[7].lanes = {1, 3}          # the one request that held lane 3
+    picked = check.sample(recs, seed)
+    assert recs[29] in picked and recs[7] in picked
+    assert set().union(*(r.lanes for r in picked)) == {0, 1, 2, 3}
+    assert len(picked) <= check.SAMPLE_MAX
+    assert sum(len(r.tokens) for r in picked) >= check.SAMPLE_TOKENS

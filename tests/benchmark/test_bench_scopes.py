@@ -155,3 +155,83 @@ def test_a_decode_of_the_chat_cell_cut_from_a_v5e_trace(monkeypatch,
     assert a0 <= a1 <= a2 and b2 <= b1 <= b0
     # spans, and no tenant arrived: the share reads 0, not nothing
     assert spec.metric_reader("handshake_idle_share.chat")(run) == 0.0
+
+
+@pytest.mark.parametrize("name", ["decode_cut.xplane.pb", "scoped.xplane.pb"])
+def test_a_nested_scope_leaves_the_engines_readings(name):
+    """The five scope readings, the decode calls and the spans of the
+    traces recorded on a v5e are the same when a reader asks for a nested
+    scope and a span of its own (which these programs do not have); the
+    nested scope reads nothing."""
+    path = spec.HERE / "testdata" / name
+    plain = scopes.read(path)
+    asked = scopes.read(path, spans=("moe.route",))
+    assert asked.scope_ns == plain.scope_ns and plain.scope_ns
+    assert (asked.decode_calls, asked.decode_ns, asked.spans) == \
+        (plain.decode_calls, plain.decode_ns, plain.spans)
+    assert asked.per_call_ms("experts") is None
+    for scope in scopes.SCOPES:
+        assert asked.per_call_ms(scope) == plain.per_call_ms(scope)
+    assert plain.per_call_ms("experts") is None
+
+
+NESTED = spec.HERE / "testdata" / "nested.xplane.pb"
+
+
+def test_a_scope_nested_in_the_layer_loop():
+    """testdata/nested.xplane.pb (make_nested.py lists its events): two
+    decode calls whose layer loop holds operations scoped ``attn`` and
+    ``experts``, one of them a loop of its own around a gather; a prefill
+    with one more."""
+    got = scopes.read(NESTED, spans=("moe.route",))
+    assert got.decode_calls == 2
+    assert got.decode_ns == pytest.approx(60e6)
+    assert got.scope_ns == pytest.approx({
+        "weights_unseal": 10e6, "model": 40e6, "kv_seal": 6e6})
+    assert got.per_call_ms("experts") == pytest.approx(14.0)
+    assert got.per_call_ms("attn") == pytest.approx(4.0)
+    assert got.per_call_ms("model") == pytest.approx(20.0)
+    assert got.per_call_ms("kv_gather") == 0.0
+    assert got.per_call_ms("router") is None    # no operation carries it
+    assert [n for n, _, _ in got.spans] == [
+        "sched.decode", "moe.route", "sched.decode", "moe.route"]
+    plain = scopes.read(NESTED)
+    assert plain.per_call_ms("experts") == pytest.approx(14.0)
+    assert plain.per_call_ms("model") == pytest.approx(20.0)
+    assert [n for n, _, _ in plain.spans] == ["sched.decode"] * 2
+
+
+@pytest.mark.parametrize("tf_op, scope, carried", [
+    ("jit(_decode_impl)/model/while/body/experts/dot_general:", "experts",
+     True),
+    ("jit(_decode_impl)/model/while/body/experts_x/dot_general:", "experts",
+     False),
+    ("jit(_decode_impl)/model/while/body/dot_general:experts", "experts",
+     False),
+    (None, "experts", False),
+])
+def test_carries(tf_op, scope, carried):
+    assert scopes.carries(tf_op, scope) is carried
+
+
+def test_a_reader_of_a_nested_scope_is_a_file(monkeypatch, tmp_path):
+    """A new reader file reads the nested ``experts`` scope from a traced
+    run through scopes.of_run, and a reader asks there for a span of its
+    own, with no edit to scopes.py and nothing listed in the
+    configuration."""
+    red = _in_trace_dir(monkeypatch, tmp_path / "trace", "nested.xplane.pb")
+    data = tmp_path / "data"
+    (data / "metrics").mkdir(parents=True)
+    (data / "metrics" / "experts_ms.py").write_text(
+        '"""Device ms per decode call of the nested ``experts`` scope."""\n'
+        "from benchmarks.chip import scopes\n\n\n"
+        "def read(run):\n"
+        "    scoped = scopes.of_run(run)\n"
+        '    return scoped.per_call_ms("experts") if scoped else None\n')
+    read = spec.metric_reader("experts_ms.moe", data)
+    run = types.SimpleNamespace(trace=red, cell=None)
+    assert read(run) == pytest.approx(14.0)
+    assert read(types.SimpleNamespace(trace=None, cell=None)) is None
+    assert spec.metric_reader("model_ms.moe")(run) == pytest.approx(20.0)
+    routes = scopes.of_run(run, spans=("moe.route",)).spans
+    assert [n for n, _, _ in routes].count("moe.route") == 2

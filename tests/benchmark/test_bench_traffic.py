@@ -27,27 +27,34 @@ def test_same_seed_same_schedule():
     assert all(np.array_equal(x.prompt, y.prompt) for x, y in zip(a, b))
     c = traffic.generate(_mix("chat"), BIG_SEED + 1, 49155, 96,
                          rate_per_s=2.0)
-    assert [r.max_new for r in a] != [r.max_new for r in c]
+    assert [(len(r.prompt), r.max_new, r.t_due) for r in a] == \
+        [(len(r.prompt), r.max_new, r.t_due) for r in c]
+    assert [r.tenant for r in a] != [r.tenant for r in c]
+    assert not any(np.array_equal(x.prompt, y.prompt) for x, y in zip(a, c))
 
 
 @pytest.mark.parametrize("mix", ["chat", "long"])
 def test_every_seed_gets_the_same_work(mix):
-    """Each block of the stream holds the same lengths (and, open, the same
-    gaps), in the seed's order, within the mix's bounds."""
+    """Every seed's stream holds the same lengths (and, open, the same
+    gaps) in the same order, each block the distribution's quantiles once,
+    within the mix's bounds."""
     m = _mix(mix)
-    n = 4 * m["block"]
+    block = m["block"]
+    n = 4 * block
     runs = [traffic.generate(m, s, 49155, n, rate_per_s=2.0)
             for s in (1, 7, BIG_SEED)]
     for field in ("prompt", "output"):
-        sizes = [sorted((len(r.prompt) if field == "prompt" else r.max_new)
-                        for r in run) for run in runs]
+        sizes = [[len(r.prompt) if field == "prompt" else r.max_new
+                  for r in run] for run in runs]
         assert sizes[0] == sizes[1] == sizes[2]
+        blocks = [sorted(sizes[0][i:i + block]) for i in range(0, n, block)]
+        assert all(b == blocks[0] for b in blocks)
         assert min(sizes[0]) >= m[field]["min"]
         assert max(sizes[0]) <= m[field]["max"]
     if m["loop"] == "open":
-        gaps = [sorted(np.diff([0.0] + [r.t_due for r in run]))
-                for run in runs]
+        gaps = [np.diff([0.0] + [r.t_due for r in run]) for run in runs]
         np.testing.assert_allclose(gaps[0], gaps[1])
+        np.testing.assert_allclose(gaps[0], gaps[2])
         assert abs(np.mean(gaps[0]) - 0.5) < 0.05
 
 
@@ -66,11 +73,13 @@ class _Req:
         self.status = "queued"
         self.prefill_pos = 0
         self.tokens_out = []
+        self.slot = -1
 
 
 class FakeGateway:
-    """Admits up to ``slots`` requests; a step prefills each new request
-    whole (its first token) and decodes one token for every running one."""
+    """Admits up to ``slots`` requests, each into the lowest free slot; a
+    step prefills each new request whole (its first token) and decodes one
+    token for every running one; a finished request leaves its slot."""
 
     def __init__(self, slots=2):
         self.slots = slots
@@ -88,7 +97,11 @@ class FakeGateway:
     def step(self):
         ev = {"emitted": [], "finished": [], "poisoned": []}
         while self.queue and len(self.active) < self.slots:
-            self.active.append(self.queue.pop(0))
+            rid = self.queue.pop(0)
+            held = {self.scheduler.requests[a].slot for a in self.active}
+            self.scheduler.requests[rid].slot = min(
+                set(range(self.slots)) - held)
+            self.active.append(rid)
         self.max_active = max(self.max_active, len(self.active))
         for rid in list(self.active):
             r = self.scheduler.requests[rid]
@@ -97,7 +110,7 @@ class FakeGateway:
             r.tokens_out.append(7)
             ev["emitted"].append((rid, 7))
             if len(r.tokens_out) >= r.max_new:
-                r.status = "done"
+                r.status, r.slot = "done", -1
                 ev["finished"].append(rid)
                 self.active.remove(rid)
         self.scheduler.idle = not (self.queue or self.active)
@@ -128,6 +141,8 @@ def test_closed_loop_keeps_its_concurrency():
     assert gw.max_active == 3
     done = [r for r in d.records if r.status == "done"]
     assert done and all(len(r.tokens) == r.req.max_new for r in done)
+    # each request decoded in the one slot it held, up to its last token
+    assert all(len(r.lanes) == 1 and r.lanes <= {0, 1, 2} for r in done)
 
 
 def test_open_loop_sends_on_schedule_and_logs_work():
